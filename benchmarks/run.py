@@ -54,6 +54,8 @@ import subprocess
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (bench_campaign, bench_decision, bench_faults, bench_profile,
                bench_roofline, bench_scale, bench_scheduler, bench_service)
 
@@ -101,6 +103,7 @@ def _emit(section: str, rows, t0: float, provenance: dict) -> None:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small workloads (CI)")
@@ -343,6 +346,9 @@ def main(argv=None) -> int:
                 print(f"VALIDATION-FAIL,{fail}", file=sys.stderr)
                 failures.append(fail)
     if want("device"):
+        # This section initialises a JAX backend, which then holds the
+        # process's devices: it must run in a process that forks nothing
+        # afterwards (a forked worker cannot use the parent's chip).
         # jax is optional in lightweight CI: skip (with a visible row)
         # rather than fail when the device backend is absent
         try:

@@ -54,11 +54,13 @@ def main():
 
     serve_state = {}
 
-    def serve_fn(job, node_ids):
-        """Run the on-demand payload on the nodes the cluster vacated."""
-        if "engine" not in serve_state:
+    def serve_fn(job, devices):
+        """Run the on-demand payload on the devices the cluster vacated."""
+        key = tuple(d.id for d in devices)
+        if key not in serve_state:
             params = init_params(jax.random.PRNGKey(9), SMALL)
-            serve_state["engine"] = ServeEngine(SMALL, params, max_seq=128)
+            serve_state[key] = ServeEngine(SMALL, params, max_seq=128,
+                                           devices=devices)
         reqs = []
         for p in plan_requests(job, vocab=SMALL.vocab):
             rng = np.random.default_rng(p["rid"])
@@ -67,9 +69,9 @@ def main():
                 prompt=rng.integers(0, SMALL.vocab, p["prompt_len"],
                                     dtype=np.int32),
                 max_new_tokens=p["max_new_tokens"]))
-        serve_state["engine"].serve_batch(reqs)
+        serve_state[key].serve_batch(reqs)
         print(f"  served {sum(len(r.tokens_out) for r in reqs)} tokens for "
-              f"{len(reqs)} requests on {len(node_ids)} vacated nodes")
+              f"{len(reqs)} requests on vacated devices {list(key)}")
         return reqs
 
     launcher = LiveClusterLauncher(cluster, job_factory, serve_fn=serve_fn,

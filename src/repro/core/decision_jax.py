@@ -16,10 +16,13 @@ reproduce its decisions job for job.
 Numerical contract (documented in docs/performance.md):
 
 * ``dtype="float64"`` (the default, and the parity gate): inputs are
-  float64/int64, traced inside :func:`repro.kernels.ops.enable_x64`, and
+  float64/int64, traced inside the scoped :func:`jax.enable_x64`, and
   every kernel is **exactly** equal to its numpy reference — the same
   IEEE expressions over the same operands, including stable sort order.
-* ``dtype="float32"``: inputs round to float32/int32.  Continuous
+  Not on a TPU: the chip has no native float64, its emulation rounds
+  differently from IEEE float64, and requesting float64 there raises.
+* ``dtype="float32"`` (the TPU contract, :func:`device_dtype`): inputs
+  round to float32/int32.  Continuous
   outputs (``t_shadow``) agree within ``FLOAT32_RTOL``; discrete
   outputs (victim sets, sheds, filter masks) may legitimately differ
   where rounding crosses a comparison or reorders a sort, but the
@@ -45,7 +48,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..kernels import ops as kops
 from .decision import DecisionTrace
 
 #: documented float32 tolerance for continuous outputs (t_shadow): the
@@ -56,8 +58,22 @@ from .decision import DecisionTrace
 FLOAT32_RTOL = 1e-6
 
 
+def device_dtype() -> str:
+    """The replay dtype whose parity contract holds on JAX's default
+    backend: exact float64, except on a TPU, where it is float32."""
+    return "float32" if jax.default_backend() == "tpu" else "float64"
+
+
 def _dtypes(dtype: str):
     if dtype == "float64":
+        if jax.default_backend() == "tpu":
+            # measured on a TPU v5e: 2,272 of the 9,904 decisions of a
+            # Theta-scale grid came back with t_shadow off by a few ulps
+            raise ValueError(
+                "float64 decision replay is not exact on a TPU: the chip "
+                "has no native float64 and its emulation rounds "
+                "differently from IEEE float64; use dtype='float32', "
+                "whose FLOAT32_RTOL contract holds there")
         return jnp.float64, jnp.int64
     if dtype == "float32":
         return jnp.float32, jnp.int32
@@ -220,7 +236,7 @@ def easy_shadow_jax(avail: int, need: int, est_end_bases, sizes, now: float,
     fdt, idt = _dtypes(dtype)
     n = len(est_end_bases)
     P = max(n, 1)
-    with kops.enable_x64(dtype == "float64"):
+    with jax.enable_x64(dtype == "float64"):
         t, extra = _easy_shadow_jit(
             jnp.asarray(avail, idt), jnp.asarray(need, idt),
             jnp.asarray(_pad(est_end_bases, P, np.inf, fdt)),
@@ -235,7 +251,7 @@ def select_preemption_victims_jax(sizes, overheads, need: int,
     fdt, idt = _dtypes(dtype)
     n = len(sizes)
     P = max(n, 1)
-    with kops.enable_x64(dtype == "float64"):
+    with jax.enable_x64(dtype == "float64"):
         order, k, surplus = _victims_jit(
             jnp.asarray(_pad(sizes, P, 0, idt)),
             jnp.asarray(_pad(overheads, P, np.inf, fdt)),
@@ -250,7 +266,7 @@ def apportion_shrink_jax(cur_sizes, min_sizes, need: int,
     P = max(n, 1)
     if need <= 0:
         return [0] * n
-    with kops.enable_x64(dtype == "float64"):
+    with jax.enable_x64(dtype == "float64"):
         ok, base = _apportion_jit(
             jnp.asarray(_pad(cur_sizes, P, 0, idt)),
             jnp.asarray(_pad(min_sizes, P, 0, idt)),
@@ -265,7 +281,7 @@ def backfill_prefilter_jax(need_mins, supply_bound: float,
     fdt, _idt = _dtypes(dtype)
     n = len(need_mins)
     P = max(n, 1)
-    with kops.enable_x64(dtype == "float64"):
+    with jax.enable_x64(dtype == "float64"):
         mask = _prefilter_jit(
             jnp.asarray(_pad(need_mins, P, np.inf, fdt)),
             jnp.arange(P) < n, jnp.asarray(supply_bound, fdt))
@@ -282,7 +298,7 @@ def backfill_shadow_filter_jax(need_mins, est_remainings, candidates,
     ests_c = np.asarray(est_remainings, dtype=np.float64)[cand]
     n = cand.size
     P = max(n, 1)
-    with kops.enable_x64(dtype == "float64"):
+    with jax.enable_x64(dtype == "float64"):
         mask = _shadow_filter_jit(
             jnp.asarray(_pad(needs_c, P, np.inf, fdt)),
             jnp.asarray(_pad(ests_c, P, np.inf, fdt)),
@@ -507,7 +523,7 @@ def run_device_sweep(cells: Sequence[Tuple[object, DecisionTrace]],
             n_cells=len(cells), n_calls=0, calls_per_kernel={},
             pad_per_kernel={}, n_dropped=n_dropped, dtype=dtype,
             parity_ok=True, build_s=build_s, compile_s=0.0, device_s=0.0)
-    with kops.enable_x64(dtype == "float64"):
+    with jax.enable_x64(dtype == "float64"):
         batches = jax.tree_util.tree_map(jnp.asarray, batches_np)
         t0 = time.perf_counter()
         outs = _sweep_program_jit(batches)

@@ -43,6 +43,7 @@ import hashlib
 import json
 import logging
 import os
+import sys
 import time
 from dataclasses import dataclass, field, fields as dataclass_fields, replace
 from typing import Dict, Iterator, List, Mapping, Optional, Sequence, Tuple, \
@@ -174,6 +175,15 @@ def _execute(spec: RunSpec) -> RunResult:
                      decision_trace=trace)
 
 
+def _jax_backend_live() -> bool:
+    """Whether this process has initialised a JAX backend (and so may
+    hold an accelerator that forked workers cannot share)."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
+
+
 @dataclass
 class Experiment:
     """A mechanisms x workloads x seeds sweep with streaming aggregation."""
@@ -271,6 +281,14 @@ class Experiment:
         pending = {i: s for i, s in enumerate(specs) if i not in set(skip)}
         if not pending:
             return
+        if n > 1 and len(pending) > 1 and _jax_backend_live():
+            # a forked worker inherits this process's hold on the device
+            # and cannot use it: run here instead
+            log.warning(
+                "Experiment: a JAX backend is live in this process; "
+                "running %d run(s) serially instead of forking workers",
+                len(pending))
+            n = 1
         if n > 1 and len(pending) > 1:
             try:
                 from concurrent.futures import ProcessPoolExecutor, \
@@ -376,8 +394,10 @@ class Experiment:
         engine stays the identity baseline).
         """
         if self.device == "jax":
-            # fail on a missing jax before paying for the sweep
+            # fail on a missing jax, or a dtype the backend cannot replay
+            # exactly, before paying for the sweep
             from . import decision_jax
+            decision_jax._dtypes(self.device_dtype)
         indexed = sorted(self._stream(), key=lambda it: it[0])
         result = ExperimentResult([r for _i, r in indexed])
         if self.device == "jax":

@@ -13,84 +13,60 @@ waste), O(S * block) live memory, O(log S) HLO size.
 """
 from __future__ import annotations
 
-import contextlib
-import functools
+import collections
 import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-_BACKEND_OVERRIDE: Optional[str] = None  # "jnp" | "pallas" | None=auto
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat constructor for the Pallas TPU compiler params.
-
-    JAX renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``
-    (and older releases only have the TPU-prefixed name), so resolve
-    whichever the installed JAX exposes — the kwargs are identical.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+# "jnp" | "pallas" | "interpret" (Pallas kernels in interpret mode, for
+# CPU rehearsals of the TPU path) | None=auto
+_BACKEND_OVERRIDE: Optional[str] = None
 
 
-def tpu_memory_space(name: str):
-    """Same rename compat for ``pltpu.MemorySpace`` (nee
-    ``TPUMemorySpace``): ``tpu_memory_space("SMEM")``."""
-    from jax.experimental.pallas import tpu as pltpu
-    enum = getattr(pltpu, "MemorySpace", None)
-    if enum is None:
-        enum = pltpu.TPUMemorySpace
-    return getattr(enum, name)
+#: the bf16 sublane tile: a Pallas block's second-to-last dim must be a
+#: multiple of it (or the whole axis)
+SEQ_ALIGN = 16
+
+#: (attention mode, implementation) -> times traced; read by callers that
+#: must show which implementation ran (e.g. chip_smoke.py)
+traced_impls: collections.Counter = collections.Counter()
 
 
-def x64_enabled() -> bool:
-    """Whether float64/int64 are live JAX types right now (global flag or
-    an enclosing :func:`enable_x64` scope)."""
-    return bool(jax.config.jax_enable_x64)
+def fit_block(n: int, want: int) -> int:
+    """Largest multiple of :data:`SEQ_ALIGN` that is <= ``want`` and
+    divides ``n``; 0 when none does (the kernels cannot tile ``n``)."""
+    for b in range(min(want, n) // SEQ_ALIGN * SEQ_ALIGN, 0, -SEQ_ALIGN):
+        if n % b == 0:
+            return b
+    return 0
 
 
-def enable_x64(enable: bool = True):
-    """Version-compat scoped x64 switch.
-
-    The scheduler decision kernels (repro.core.decision_jax) need exact
-    float64 parity with their numpy references without flipping the
-    global ``jax_enable_x64`` flag — the model/kernel suites in the same
-    process rely on float32/bf16 canonicalization.  Prefers the
-    thread-local ``jax.experimental.enable_x64`` context manager and
-    falls back to saving/restoring the global flag on JAX versions
-    without it.
-    """
-    ctx = getattr(jax.experimental, "enable_x64", None)
-    if ctx is not None:
-        return ctx(enable)
-
-    @contextlib.contextmanager
-    def _flag_scope():
-        prev = bool(jax.config.jax_enable_x64)
-        jax.config.update("jax_enable_x64", enable)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", prev)
-    return _flag_scope()
+def padded_len(n: int) -> int:
+    """``n`` rounded up to a length the attention kernels can tile."""
+    return -(-n // SEQ_ALIGN) * SEQ_ALIGN
 
 
-def set_backend(name: Optional[str]) -> None:
+def set_backend(name: Optional[str]) -> Optional[str]:
+    """Steer attention to an implementation; returns the previous choice,
+    so a caller can restore it."""
     global _BACKEND_OVERRIDE
-    _BACKEND_OVERRIDE = name
+    prev, _BACKEND_OVERRIDE = _BACKEND_OVERRIDE, name
+    return prev
 
 
 def _use_pallas() -> bool:
-    if _BACKEND_OVERRIDE == "pallas":
+    if _BACKEND_OVERRIDE in ("pallas", "interpret"):
         return True
     if _BACKEND_OVERRIDE == "jnp":
         return False
     return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    return _BACKEND_OVERRIDE == "interpret"
 
 
 # ============================================================== soft helpers
@@ -114,7 +90,9 @@ def _sdp(qg, k, v, scale, mask=None):
     p = jnp.exp(s - m)
     den = jnp.sum(p, axis=-1)                      # (..., K, G, Sq)
     o = jnp.einsum("...kgst,...tkd->...skgd", p, v)
-    o = o / jnp.moveaxis(den, -1, -3)[..., None]
+    # a fully masked row (left padding) has den 0: keep it finite, since
+    # 0 * NaN in a later p @ v would reach the real rows
+    o = o / jnp.maximum(jnp.moveaxis(den, -1, -3), 1e-30)[..., None]
     lse = m[..., 0] + jnp.log(jnp.maximum(den, 1e-30))
     return o, jnp.moveaxis(lse, -1, -3)            # lse -> (..., Sq, K, G)
 
@@ -198,42 +176,64 @@ def _causal_binary(qg, k, v, scale, block_q: int, block_kv: int):
 
 # ================================================================= attention
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
-              kv_valid_len=None, block_q: int = 512, block_kv: int = 1024):
+              kv_valid_len=None, kv_start=None, block_q: int = 512,
+              block_kv: int = 1024):
     """Multi-head attention with GQA.
 
     q: (B, Sq, H, D); k/v: (B, Skv, K, Dk/Dv), H % K == 0.
       * kv_valid_len set   -> decode against a cache (mask t > pos).
       * causal             -> exact binary-blocked causal attention.
       * else               -> full (cross/encoder) attention, kv-chunked.
+    kv_start (B,) int32, if given, masks keys before each row's first
+    real token (left-padded serving batches).
+
+    On TPU the Pallas kernels take causal self-attention and single-token
+    decode whenever :func:`fit_block` can tile the lengths; every other
+    shape takes the jnp path.
     """
     B, Sq, H, D = q.shape
     K = k.shape[2]
     G = H // K
+    Skv = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     ct = q.dtype
     qg = q.reshape(B, Sq, K, G, D)
+    mode = ("decode" if kv_valid_len is not None
+            else "causal" if causal else "full")
 
-    if _use_pallas() and kv_valid_len is None and causal and Sq == k.shape[1]:
-        from . import flash_attention as fa
-        return fa.flash_attention(q, k, v, causal=True, scale=scale,
-                                  block_q=block_q, block_kv=block_kv)
+    if _use_pallas():
+        starts = (jnp.zeros((B,), jnp.int32) if kv_start is None
+                  else jnp.asarray(kv_start, jnp.int32))
+        if mode == "causal" and Sq == Skv and fit_block(Sq, block_q) \
+                and fit_block(Skv, block_kv):
+            from . import flash_attention as fa
+            traced_impls[(mode, "pallas")] += 1
+            return _per_shard(
+                lambda q, k, v, st: fa.flash_attention(
+                    q, k, v, st, causal=True, scale=scale, block_q=block_q,
+                    block_kv=block_kv, interpret=_interpret()),
+                (q, k, v, starts), (q, k, v, starts))
+        if mode == "decode" and Sq == 1 and v.shape[-1] == D \
+                and fit_block(Skv, block_kv):
+            from . import flash_decode as fd
+            traced_impls[(mode, "pallas")] += 1
+            return _per_shard(
+                lambda q, k, v, st, vl: fd.flash_decode(
+                    q, k, v, vl, st, scale=scale, block_kv=block_kv,
+                    interpret=_interpret()),
+                (q, k, v, starts, jnp.asarray(kv_valid_len, jnp.int32)),
+                (q, k, v, starts))
+    traced_impls[(mode, "jnp")] += 1
 
-    if _use_pallas() and kv_valid_len is not None and Sq == 1 \
-            and k.shape[1] % min(block_kv, k.shape[1]) == 0:
-        from . import flash_decode as fd
-        return fd.flash_decode(q, k, v, kv_valid_len, scale=scale,
-                               block_kv=block_kv)
-
-    if kv_valid_len is None and causal and Sq == k.shape[1] and Sq > block_q \
+    if mode == "causal" and kv_start is None and Sq == Skv and Sq > block_q \
             and Sq % block_q == 0 and _is_pow2(Sq // block_q):
         out = _causal_binary(qg.astype(jnp.float32), k.astype(jnp.float32),
                              v.astype(jnp.float32), scale, block_q, block_kv)
         return out.reshape(B, Sq, H, -1).astype(ct)
 
-    # ---- small / decode / cross path ---------------------------------------
+    # ---- small / decode / cross / padded path ------------------------------
     qf = qg.astype(jnp.float32)
     kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
-    Skv = k.shape[1]
     ti = jnp.arange(Skv)
     mask = None
     if kv_valid_len is not None:
@@ -241,9 +241,35 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
         mask = (ti[None, :] <= qpos[:, None])[None, None, None]
     elif causal:
         mask = (ti[None, :] <= jnp.arange(Sq)[:, None] + (Skv - Sq))[None, None, None]
+    if kv_start is not None:
+        live = (ti[None, :] >= jnp.asarray(kv_start)[:, None])
+        live = live[:, None, None, None, :]               # (B, 1, 1, 1, T)
+        mask = live if mask is None else mask & live
     o, _ = _sdp(qf[:, None], kf[:, None], vf[:, None], scale,
                 mask=mask[:, None] if mask is not None else None)
     return o[:, 0].reshape(B, Sq, H, -1).astype(ct)
+
+
+def _per_shard(kernel, args, batched):
+    """Run a Pallas kernel under the model's mesh, if one is set.
+
+    XLA cannot partition a Mosaic kernel, so on a multi-device mesh each
+    device runs it on its own shard of the batch (``jax.shard_map``);
+    the arguments in ``batched`` are split over the batch axes when the
+    batch divides, and every other argument is replicated.
+    """
+    from repro.models import dist          # the mesh the model runs under
+    mesh = dist.get_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(*args)
+    axes = dist.batch_axes()
+    n = math.prod(mesh.shape[a] for a in axes)
+    split = args[0].shape[0] % n == 0
+    bspec = P(axes if len(axes) > 1 else axes[0]) if split else P()
+    specs = tuple(bspec if any(a is b for b in batched) else P()
+                  for a in args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=bspec,
+                         check_vma=False)(*args)
 
 
 def _is_pow2(n: int) -> bool:
@@ -262,7 +288,8 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256,
     """
     if _use_pallas() and not return_final_state:
         from . import ssd_scan as kern
-        return kern.ssd_scan(x, dt, A, B, C, D, chunk=chunk)
+        return kern.ssd_scan(x, dt, A, B, C, D, chunk=chunk,
+                             interpret=_interpret())
     return _ssd_jnp(x, dt, A, B, C, D, chunk, return_final_state)
 
 

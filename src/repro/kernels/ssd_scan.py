@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ops import tpu_compiler_params
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, state_ref, *,
             chunk: int):
@@ -89,7 +87,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256, interpret: bool = False):
         out_specs=pl.BlockSpec((1, chunk, 1, p), lambda i, j, c: (i, c, j, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, B, C, D)

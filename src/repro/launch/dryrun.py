@@ -78,13 +78,8 @@ def collective_stats(hlo: str) -> dict:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Version-compat wrapper for ``Compiled.cost_analysis()``: newer jax
-    returns a per-program list of dicts where older jax returned the dict
-    itself.  Returns the (first) program's flat {counter: value} dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """The flat {counter: value} dict of ``Compiled.cost_analysis()``."""
+    return compiled.cost_analysis()
 
 
 def _arch_cfg(arch: str) -> ModelConfig:

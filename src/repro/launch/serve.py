@@ -11,6 +11,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ALIASES, get_config
 from repro.configs.reduced import reduce_config
 from repro.models import init_params
@@ -18,6 +19,7 @@ from repro.serving import Request, ServeEngine
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -17,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ALIASES, get_config
 from repro.configs.reduced import reduce_config
 from repro.launch.mesh import make_mesh
@@ -34,6 +35,7 @@ def parse_mesh(spec: str, axis_names=("data", "model")):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--mesh", default=None, help="e.g. 16x16 or 2x16x16")

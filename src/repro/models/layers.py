@@ -102,11 +102,13 @@ def gqa_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
             cache: Optional[Tuple[jax.Array, jax.Array]] = None,
             cache_index: Optional[jax.Array] = None,
             kv_source: Optional[jax.Array] = None,
-            causal: bool = True, return_kv: bool = False):
+            causal: bool = True, return_kv: bool = False,
+            kv_start: Optional[jax.Array] = None):
     """GQA/MQA attention.  Modes:
        * train/prefill: cache is None, full self-attention over x.
        * decode:        cache=(k,v) with (B,S,K,Dh); writes at cache_index.
        * cross:         kv_source given (encoder memory), no rope on kv.
+    kv_start (B,) masks each row's left padding (serving batches).
     Returns (out, new_cache).
     """
     ct = jnp.dtype(cfg.compute_dtype)
@@ -126,7 +128,7 @@ def gqa_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
         # update — a full-size zeros+dynamic-update carry costs ~2x the
         # cache in live temps; see EXPERIMENTS.md deepseek iteration)
         out = kops.attention(q, k, v, causal=causal and kv_source is None,
-                             block_q=cfg.attn_block_q,
+                             kv_start=kv_start, block_q=cfg.attn_block_q,
                              block_kv=cfg.attn_block_kv)
         out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(ct))
         return out, (k, v)
@@ -138,7 +140,8 @@ def gqa_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
         k, v = ck, cv
         kv_len = jnp.asarray(cache_index + x.shape[1], jnp.int32)
         out = kops.attention(q, k, v, causal=False, kv_valid_len=kv_len,
-                             block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv)
+                             kv_start=kv_start, block_q=cfg.attn_block_q,
+                             block_kv=cfg.attn_block_kv)
     else:
         out = kops.attention(q, k, v, causal=causal and kv_source is None,
                              block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv)
@@ -167,7 +170,8 @@ def mla_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
             positions: jax.Array,
             cache: Optional[Tuple[jax.Array, jax.Array]] = None,
             cache_index: Optional[jax.Array] = None,
-            causal: bool = True, return_kv: bool = False):
+            causal: bool = True, return_kv: bool = False,
+            kv_start: Optional[jax.Array] = None):
     """Multi-head latent attention (DeepSeek-V2).
 
     Cache stores only (c_kv, k_rope): (B,S,kv_lora) + (B,S,d_rope) — the
@@ -197,7 +201,8 @@ def mla_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
                                       (*k_nope.shape[:3], m.d_rope))], -1)
         qf = jnp.concatenate([q_nope, q_rope], -1)
         out = kops.attention(qf, k, v, causal=causal, scale=scale,
-                             block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv)
+                             kv_start=kv_start, block_q=cfg.attn_block_q,
+                             block_kv=cfg.attn_block_kv)
         out = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(ct))
         return out, ((c_kv, k_rope) if return_kv else None)
 
@@ -217,8 +222,10 @@ def mla_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
                            cr.astype(f32))) * scale
     t = jnp.arange(cc.shape[1])
     qpos = cache_index + jnp.arange(x.shape[1])     # per-query causal mask
-    mask = t[None, :] <= qpos[:, None]
-    scores = jnp.where(mask[None, None], scores, -jnp.inf)
+    mask = (t[None, :] <= qpos[:, None])[None, None]
+    if kv_start is not None:
+        mask = mask & (t[None, :] >= kv_start[:, None])[:, None, None]
+    scores = jnp.where(mask, scores, -jnp.inf)
     attn = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhst,btc->bshc", attn, cc.astype(f32))
     out = jnp.einsum("bshc,chv->bshv", ctx, wb_v).astype(ct)  # absorb o-proj

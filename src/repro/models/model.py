@@ -72,13 +72,13 @@ def _init_block(key, cfg: ModelConfig, moe_layer: bool) -> Params:
 
 def _block_fwd(p: Params, x, cfg: ModelConfig, *, positions, cache=None,
                cache_index=None, causal=True, moe_layer=False,
-               return_kv=False):
+               return_kv=False, kv_start=None):
     x = dist.constrain_batch(x)
     attn_fn = mla_fwd if cfg.mla else gqa_fwd
     h, new_cache = attn_fn(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps), cfg,
                            positions=positions, cache=cache,
                            cache_index=cache_index, causal=causal,
-                           return_kv=return_kv)
+                           return_kv=return_kv, kv_start=kv_start)
     x = x + h
     hn = rmsnorm(p["ln2"], x, cfg.norm_eps)
     if moe_layer:
@@ -347,9 +347,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int):
     raise ValueError(fam)
 
 
-def decode_step(params: Params, cache, tokens, pos, cfg: ModelConfig):
+def decode_step(params: Params, cache, tokens, pos, cfg: ModelConfig,
+                kv_start=None):
     """One token for every sequence.  tokens: (B, 1); pos: scalar index.
-    Returns (logits (B, V), new_cache)."""
+    kv_start (B,), attention families only: cache slots before it are
+    left padding and masked.  Returns (logits (B, V), new_cache)."""
     fam = cfg.family
     x = embed(params["embed"], tokens, cfg)
     B = x.shape[0]
@@ -360,7 +362,8 @@ def decode_step(params: Params, cache, tokens, pos, cfg: ModelConfig):
             lp, ck = xs
             h2, new_ck, _ = _block_fwd(lp, h, cfg, positions=positions,
                                        cache=ck, cache_index=pos,
-                                       moe_layer=cfg.moe is not None)
+                                       moe_layer=cfg.moe is not None,
+                                       kv_start=kv_start)
             return h2, new_ck
         new_cache = dict(cache)
         if "pre_layers" in params:
@@ -368,7 +371,7 @@ def decode_step(params: Params, cache, tokens, pos, cfg: ModelConfig):
                 lp, ck = xs
                 h2, new_ck, _ = _block_fwd(lp, h, cfg, positions=positions,
                                            cache=ck, cache_index=pos,
-                                           moe_layer=False)
+                                           moe_layer=False, kv_start=kv_start)
                 return h2, new_ck
             x, new_cache["pre_layers"] = jax.lax.scan(
                 pre_body, x, (params["pre_layers"], cache["pre_layers"]))
@@ -440,11 +443,13 @@ def _hybrid_decode(params, cache, x, positions, pos, cfg: ModelConfig):
 
 # ---------------------------------------------------------------- prefill
 def prefill(params: Params, tokens, cfg: ModelConfig,
-            extra: Optional[jax.Array] = None):
+            extra: Optional[jax.Array] = None, kv_start=None):
     """Process a full prompt; returns (last-token logits, cache).
 
     Implemented as forward + cache extraction for the attention families;
     recurrent families run their chunked scans and keep final states.
+    kv_start (B,), dense/moe/vlm only: tokens before it are left padding
+    and masked as keys.
     """
     fam = cfg.family
     B, S = tokens.shape
@@ -462,13 +467,14 @@ def prefill(params: Params, tokens, cfg: ModelConfig,
         def body(h, lp):
             h2, kv, _ = _block_fwd(lp, h, cfg, positions=positions,
                                    moe_layer=cfg.moe is not None,
-                                   return_kv=True)
+                                   return_kv=True, kv_start=kv_start)
             return h2, kv
         cache = {}
         if "pre_layers" in params:
             def pre_body(h, lp):
                 h2, kv, _ = _block_fwd(lp, h, cfg, positions=positions,
-                                       moe_layer=False, return_kv=True)
+                                       moe_layer=False, return_kv=True,
+                                       kv_start=kv_start)
                 return h2, kv
             x, cache["pre_layers"] = jax.lax.scan(
                 pre_body, x, params["pre_layers"])

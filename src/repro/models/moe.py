@@ -18,7 +18,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
@@ -142,13 +141,13 @@ def moe_fwd(p: Params, x: jax.Array, cfg: ModelConfig
             t = jax.lax.psum(t, ba + ("model",))
             return y.reshape(xs.shape), fp, asg, t
 
-        y, fp, asg, t = shard_map(
+        y, fp, asg, t = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(_flat_batch_spec(), None, None),
                       P(None, None), P("model", None, None),
                       P("model", None, None), P("model", None, None)),
             out_specs=(P(_flat_batch_spec(), None, None), P(None), P(None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], p["w1"], p["w3"], p["w2"])
     else:
         cap = _capacity(B * S, cfg)

@@ -305,7 +305,6 @@ def slstm_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
             # happens ONCE at the boundary instead of per scan step (XLA
             # otherwise emits an all-reduce of dW_r inside the 4096-step
             # time loop — see EXPERIMENTS.md §Perf xlstm iteration).
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             bspec = ba if len(ba) > 1 else ba[0]
 
@@ -313,11 +312,11 @@ def slstm_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
                 return _slstm_scan(w_r_, st_, gx_)
 
             st_spec = SLSTMState(*([P(bspec)] * 4))
-            st, ys = shard_map(
+            st, ys = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), st_spec, P(bspec)),
                 out_specs=(st_spec, P(bspec)),
-                check_rep=False)(w_r, st, gx)
+                check_vma=False)(w_r, st, gx)
         else:
             st, ys = _slstm_scan(w_r, st, gx)
         ys = ys.astype(ct)

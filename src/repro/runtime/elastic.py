@@ -7,14 +7,16 @@ expansion" + resume):
   start(devices)        jit + (init | restore) onto a mesh over `devices`
   step(batch?)          one train step (auto data pipeline)
   preempt(warning)      malleable: 2-min-warning checkpoint at the exact
-                        step; rigid: fall back to the last periodic ckpt
+                        step; rigid: fall back to the last periodic ckpt.
+                        Device arrays are freed once a checkpoint exists
+                        to resume from, and kept otherwise
   shrink/expand(devs)   re-shard the *live* train state onto a different
                         mesh (checkpoint-free elastic resize)
   resume(devices)       start() from the persisted checkpoint
 
 Re-sharding uses jax.device_put with the new mesh's NamedShardings — the
 runtime-measured cost of the paper's "negligible" malleable resize
-assumption (recorded in EXPERIMENTS.md).
+assumption (``resize_costs``).
 """
 from __future__ import annotations
 
@@ -27,7 +29,7 @@ from jax.sharding import Mesh
 
 from repro.models import init_params, set_mesh
 from repro.models.config import ModelConfig
-from repro.sharding import batch_axes, tree_shardings
+from repro.sharding import batch_axes, batch_sharding, tree_shardings
 from repro.training import (AdamW, checkpoint, make_train_state,
                             make_train_step, synthetic_batch)
 from .straggler import StragglerMonitor
@@ -64,41 +66,56 @@ class ElasticJob:
 
     def _jit(self):
         set_mesh(self.mesh, batch_axes(self.mesh))
-        shardings = None
-        step = make_train_step(self.cfg, self.opt)
+        step = make_train_step(self.cfg, self.opt,
+                               microbatches=self.cfg.train_microbatches)
         self._step_fn = jax.jit(step, donate_argnums=(0,))
 
     # ----------------------------------------------------------------- start
+    def _init_state(self):
+        return make_train_state(
+            init_params(jax.random.PRNGKey(self.seed), self.cfg), self.opt)
+
     def start(self, devices: Sequence) -> None:
         self.devices = list(devices)
         self.mesh = self._build(self.devices)
         self._jit()
         if self.state is None:
-            with self.mesh:
-                params = init_params(jax.random.PRNGKey(self.seed), self.cfg)
-                self.state = make_train_state(params, self.opt)
+            # initialise straight onto the mesh: no staging copy on the
+            # default device, which may belong to another job
+            sh = tree_shardings(jax.eval_shape(self._init_state), self.cfg,
+                                self.mesh)
+            self.state = jax.jit(self._init_state, out_shardings=sh)()
         else:
             self._reshard()
 
     def resume(self, devices: Sequence) -> None:
+        """Restore the newest checkpoint straight onto ``devices``; a job
+        preempted before its first checkpoint kept its state and restarts
+        from that."""
         assert self.ckpt_dir is not None
+        step = checkpoint.latest_step(self.ckpt_dir)
+        if step is None:
+            return self.start(devices)
         self.devices = list(devices)
         self.mesh = self._build(self.devices)
         self._jit()
-        template = self.state
-        if template is None:
-            with self.mesh:
-                params = init_params(jax.random.PRNGKey(self.seed), self.cfg)
-                template = make_train_state(params, self.opt)
-        self.state = checkpoint.restore(self.ckpt_dir, template)
-        self.step_idx = checkpoint.latest_step(self.ckpt_dir)
-        self._reshard()
+        self._free()
+        template = jax.eval_shape(self._init_state)
+        self.state = checkpoint.restore(
+            self.ckpt_dir, template, step=step,
+            shardings=tree_shardings(template, self.cfg, self.mesh))
+        self.step_idx = step
 
     # ------------------------------------------------------------------ step
-    def step(self) -> dict:
-        t0 = time.perf_counter()
+    def next_batch(self):
+        """The next step's synthetic batch, placed on the job's mesh."""
         batch = synthetic_batch(self.cfg, self.batch, self.seq,
                                 seed=self.seed, step=self.step_idx)
+        return jax.device_put(batch, batch_sharding(batch, self.mesh))
+
+    def step(self) -> dict:
+        t0 = time.perf_counter()
+        batch = self.next_batch()
         # tracing happens on the first call after (re)jit: the sharding-
         # constraint mesh context must be THIS job's mesh at that moment
         set_mesh(self.mesh, batch_axes(self.mesh))
@@ -122,9 +139,20 @@ class ElasticJob:
         periodic checkpoint (paper §III-A)."""
         if self.ckpt_dir is not None and (warning or self.kind == "malleable"):
             self.checkpoint()
+        if self.ckpt_dir is not None and \
+                checkpoint.latest_step(self.ckpt_dir) is not None:
+            self._free()      # resume restores; the nodes go to the next job
         self.mesh = None
         self._step_fn = None
         self.devices = ()
+
+    def _free(self) -> None:
+        """Release the train state's device buffers now, not whenever the
+        last reference dies."""
+        if self.state is not None:
+            for leaf in jax.tree.leaves(self.state):
+                leaf.delete()
+        self.state = None
 
     # -------------------------------------------------------- shrink/expand
     def resize(self, devices: Sequence) -> float:

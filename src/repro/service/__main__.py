@@ -15,6 +15,7 @@ import json
 import math
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.workloads import get_scenario, registered_scenarios
 
 from .daemon import SchedulerService, ServiceConfig, shadow_fidelity
@@ -23,6 +24,7 @@ from .slo import SloPolicy
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.service",
         description="Shadow-mode scheduler service replay.")

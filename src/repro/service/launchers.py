@@ -72,12 +72,12 @@ class NullLauncher(Launcher):
 
 
 def plan_requests(job: JobSpec, max_batch: int = 8,
-                  vocab: int = 1024) -> List[dict]:
+                  vocab: int = 1024, per_node: int = 1) -> List[dict]:
     """The deterministic inference-request batch an on-demand job admits
-    to the serving engine: one request per node up to ``max_batch``,
-    prompt length and token budget derived from the jid so shadow and
-    live runs plan the identical batch."""
-    n = max(1, min(int(job.size), max_batch))
+    to the serving engine: ``per_node`` requests per node up to
+    ``max_batch``, prompt length and token budget derived from the jid so
+    shadow and live runs plan the identical batch."""
+    n = max(1, min(int(job.size) * per_node, max_batch))
     return [{"rid": job.jid * max_batch + i,
              "prompt_len": 8 + (job.jid * 7 + i * 3) % 56,
              "max_new_tokens": 16,
@@ -289,9 +289,10 @@ class LiveClusterLauncher(Launcher):
     """Execute decisions on a real :class:`repro.runtime.LiveCluster`.
 
     ``job_factory(job: JobSpec) -> ElasticJob`` builds the training
-    payload for rigid/malleable jobs; ``serve_fn(job, node_ids)`` (if
-    given) runs the inference batch for an on-demand start on the nodes
-    the cluster vacated.  The *cluster's own* registry-resolved arrival
+    payload for rigid/malleable jobs; ``serve_fn(job, devices)`` (if
+    given) runs the inference batch for an on-demand start on the
+    devices of the nodes the cluster vacated (``od_nodes`` keeps their
+    ids).  The *cluster's own* registry-resolved arrival
     policy picks shrink/preemption victims when on-demand demand arrives
     — the service's shadow ledger stays authoritative for WHAT starts
     WHEN, the cluster for WHICH physical nodes move (see
@@ -301,7 +302,7 @@ class LiveClusterLauncher(Launcher):
     """
 
     def __init__(self, cluster, job_factory: Callable[[JobSpec], object],
-                 serve_fn: Optional[Callable[[JobSpec, List[int]], object]]
+                 serve_fn: Optional[Callable[[JobSpec, List], object]]
                  = None, steps_per_tick: int = 1,
                  target_steps: int = 20):
         self.cluster = cluster
@@ -318,7 +319,8 @@ class LiveClusterLauncher(Launcher):
             nodes = self.cluster.acquire_for_ondemand(size)
             self.od_nodes[job.jid] = nodes
             if self.serve_fn is not None:
-                self.served.append(self.serve_fn(job, nodes))
+                devices = [self.cluster.devices[i] for i in nodes]
+                self.served.append(self.serve_fn(job, devices))
             return
         if job.jid in self.infos:       # restart after preemption
             return                      # cluster resumes it on free nodes

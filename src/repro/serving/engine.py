@@ -1,21 +1,25 @@
 """Batched serving engine for on-demand jobs.
 
-Prefill + greedy decode with a fixed-capacity KV cache and simple
-continuous batching: requests are grouped into a padded batch, prefilled
-once, then decoded step-by-step; finished sequences are masked out.  This
+Prefill + greedy decode with a fixed-capacity KV cache: requests are
+grouped into one left-padded batch (pad slots masked as keys), prefilled
+once, then decoded step-by-step; finished sequences stop emitting.  This
 is the execution payload of the paper's *on-demand* job class.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
-from repro.models import decode_step, init_cache, prefill
+from repro.kernels import ops as kops
+from repro.models import decode_step, dist, prefill
 from repro.models.config import ModelConfig
 
 
@@ -40,80 +44,143 @@ class Request:
     done_at: Optional[float] = None
 
 
+def pad_batch(prompts: Sequence[np.ndarray]) -> Tuple[np.ndarray, np.ndarray]:
+    """Left-pad prompts to one length the attention kernels can tile.
+
+    Returns (tokens (B, S) int32, kv_start (B,) int32): row i's real
+    tokens fill ``tokens[i, kv_start[i]:]``, so every last token sits at
+    S - 1; the model masks the pad slots before ``kv_start`` as keys.
+    """
+    lens = np.asarray([len(p) for p in prompts])
+    S = kops.padded_len(int(lens.max()))
+    toks = np.zeros((len(prompts), S), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, S - lens[i]:] = p
+    return toks, (S - lens).astype(np.int32)
+
+
 class ServeEngine:
-    """Greedy batched decoding over a fixed max_seq cache."""
+    """Greedy batched decoding over a fixed max_seq cache.
+
+    ``devices`` are the devices of the nodes the engine was handed: its
+    params are replicated there, each batch is split over them when its
+    size divides, and the cache follows the batch.  ``None`` leaves
+    placement to JAX's default device.
+    """
 
     def __init__(self, cfg: ModelConfig, params, *, max_seq: int = 512,
-                 eos_id: Optional[int] = None, donate_cache: bool = True):
+                 eos_id: Optional[int] = None, donate_cache: bool = True,
+                 devices: Optional[Sequence] = None):
         if cfg.family not in ("dense", "moe", "vlm"):
             raise NotImplementedError(
                 "ServeEngine drives attention-family LMs; recurrent archs "
                 "serve via decode_step directly")
         self.cfg = cfg
-        self.params = params
-        self.max_seq = max_seq
+        self.max_seq = kops.padded_len(max_seq)
         self.eos_id = eos_id
+        self.mesh: Optional[Mesh] = None
+        if devices is not None:
+            devs = list(devices)
+            self.mesh = Mesh(np.asarray(devs).reshape(len(devs), 1),
+                             ("data", "model"))
+            params = jax.device_put(params, NamedSharding(self.mesh, P()))
+        self.params = params
         self._prefill = jax.jit(
-            lambda p, t: prefill(p, t, cfg))
+            lambda p, t, st: prefill(p, t, cfg, kv_start=st))
         self._decode = jax.jit(
-            lambda p, c, t, pos: decode_step(p, c, t, pos, cfg),
+            lambda p, c, t, pos, st: decode_step(p, c, t, pos, cfg,
+                                                 kv_start=st),
             donate_argnums=(1,) if donate_cache else ())
+
+    @contextlib.contextmanager
+    def _placed(self):
+        """Trace and run under this engine's mesh (the model's sharding
+        constraints read it), restoring the caller's afterwards."""
+        prev = (dist.get_mesh(), dist.batch_axes())
+        dist.set_mesh(self.mesh, ("data",))
+        try:
+            yield
+        finally:
+            dist.set_mesh(*prev)
+
+    def _put(self, x: np.ndarray):
+        if self.mesh is None:
+            return jnp.asarray(x)
+        n = self.mesh.shape["data"]
+        spec = P("data") if x.shape[0] % n == 0 else P()
+        return jax.device_put(x, NamedSharding(self.mesh, spec))
+
+    def _start(self, prompts):
+        """Prefill a padded batch; returns (first tokens, cache, kv_start,
+        prompt length, last-position logits)."""
+        toks, kv_start = pad_batch(prompts)
+        S = toks.shape[1]
+        if S > self.max_seq:
+            raise ValueError(f"padded prompt length {S} exceeds the "
+                             f"{self.max_seq}-slot cache")
+        kv_start = self._put(kv_start)
+        logits, cache = self._prefill(self.params, self._put(toks), kv_start)
+        cache = jax.tree.map(lambda c: _grow(c, self.max_seq), cache)
+        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return next_tok, cache, kv_start, S, logits
 
     def serve_batch(self, requests: List[Request]) -> List[Request]:
         """Run a padded batch of requests to completion."""
-        B = len(requests)
-        lens = [len(r.prompt) for r in requests]
-        S = max(lens)
-        toks = np.zeros((B, S), np.int32)
-        for i, r in enumerate(requests):
-            toks[i, S - lens[i]:] = r.prompt    # left-pad to align last token
-        logits, cache = self._prefill(self.params, jnp.asarray(toks))
-        # grow cache to max_seq
-        cache = jax.tree.map(
-            lambda c: _grow(c, self.max_seq), cache)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        live = np.ones((B,), bool)
-        n_steps = max(r.max_new_tokens for r in requests)
-        now = time.monotonic()
-        for i, r in enumerate(requests):
-            r.first_token_at = now
-            r.tokens_out.append(int(next_tok[i]))
-        for step in range(1, n_steps):
-            pos = S + step - 1
-            if pos >= self.max_seq:
-                break
-            logits, cache = self._decode(self.params, cache,
-                                         next_tok[:, None], pos)
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            toks_np = np.asarray(next_tok)
+        with self._placed():
+            next_tok, cache, kv_start, S, _ = self._start(
+                [r.prompt for r in requests])
+            toks_np = np.asarray(next_tok)          # waits for the device
+            now = time.monotonic()
+            live = np.ones((len(requests),), bool)
             for i, r in enumerate(requests):
-                if not live[i]:
-                    continue
+                r.first_token_at = now
                 r.tokens_out.append(int(toks_np[i]))
-                if len(r.tokens_out) >= r.max_new_tokens or \
-                        (self.eos_id is not None and toks_np[i] == self.eos_id):
-                    live[i] = False
-                    r.done_at = time.monotonic()
-            if not live.any():
-                break
+            n_steps = max(r.max_new_tokens for r in requests)
+            for step in range(1, n_steps):
+                pos = S + step - 1
+                if pos >= self.max_seq:
+                    break
+                logits, cache = self._decode(self.params, cache,
+                                             next_tok[:, None], pos, kv_start)
+                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                toks_np = np.asarray(next_tok)
+                for i, r in enumerate(requests):
+                    if not live[i]:
+                        continue
+                    r.tokens_out.append(int(toks_np[i]))
+                    if len(r.tokens_out) >= r.max_new_tokens or \
+                            (self.eos_id is not None
+                             and toks_np[i] == self.eos_id):
+                        live[i] = False
+                        r.done_at = time.monotonic()
+                if not live.any():
+                    break
         now = time.monotonic()
         for r in requests:
             r.done_at = r.done_at or now
         return requests
 
+    def step_logits(self, prompt: np.ndarray,
+                    continuation: Sequence[int]) -> np.ndarray:
+        """Float32 logits (1 + len(continuation), vocab) of one request:
+        after its prefill, then after each given continuation token
+        (teacher-forced, so two attention implementations are compared
+        on the same inputs even where their greedy picks would differ)."""
+        with self._placed():
+            _, cache, kv_start, S, logits = self._start([prompt])
+            out = [np.asarray(logits[0], np.float32)]
+            for step, tok in enumerate(continuation):
+                logits, cache = self._decode(
+                    self.params, cache, self._put(np.full((1, 1), tok,
+                                                          np.int32)),
+                    S + step, kv_start)
+                out.append(np.asarray(logits[0], np.float32))
+        return np.stack(out)
+
 
 def _grow(c, max_seq: int):
-    """Pad a prefill-sized cache array out to max_seq on its seq axis."""
-    # attention caches have the seq axis at -3 (L,B,S,K,D) or -2 (L,B,S,C)
-    for ax in (-3, -2):
-        if c.ndim >= abs(ax) and c.shape[ax] not in (0,) and \
-                c.ndim >= 3 and c.shape[ax] < max_seq and _looks_seq(c, ax):
-            pad = [(0, 0)] * c.ndim
-            pad[ax] = (0, max_seq - c.shape[ax])
-            return jnp.pad(c, pad)
-    return c
-
-
-def _looks_seq(c, ax: int) -> bool:
-    # heuristic: the seq axis is the largest axis of an attention cache
-    return c.shape[ax] == max(c.shape)
+    """Pad a prefill-sized cache array out to max_seq slots.  Every cache
+    the engine serves (GQA k/v and MLA latents) is (L, B, S, ...)."""
+    pad = [(0, 0)] * c.ndim
+    pad[2] = (0, max_seq - c.shape[2])
+    return jnp.pad(c, pad)

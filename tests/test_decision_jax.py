@@ -240,3 +240,34 @@ def test_capture_trace_survives_pickle_and_fanout_shape():
     cells = [("cell0", tr2)]
     rep = J.run_device_sweep(cells)
     assert rep.parity_ok and rep.n_calls == 2
+
+
+def test_experiment_forks_no_worker_once_jax_holds_a_backend(caplog):
+    """A forked worker cannot use its parent's device: once this process
+    has initialised JAX, fan-out runs the cells here, with a warning."""
+    import jax
+    jax.devices()                                # initialise the backend
+    kw = dict(mechanisms=["BASE", "CUA&SPAA"],
+              workloads=[WorkloadConfig(n_jobs=20, notice_mix="W1")],
+              seeds=(0,))
+    with caplog.at_level("WARNING", logger="repro.core.experiment"):
+        fanned = Experiment(processes=2, **kw).run()
+    assert "running 2 run(s) serially" in caplog.text
+    serial = Experiment(processes=0, **kw).run()
+    assert [r.metrics.as_dict() for r in fanned] == \
+        [r.metrics.as_dict() for r in serial]
+
+
+def test_float64_replay_is_refused_on_a_tpu(monkeypatch):
+    """A TPU emulates float64 and cannot keep the exact contract: the
+    declared device dtype there is float32, and float64 raises before the
+    sweep runs."""
+    assert J.device_dtype() == "float64"          # this backend: exact
+    monkeypatch.setattr(J.jax, "default_backend", lambda: "tpu")
+    assert J.device_dtype() == "float32"
+    with pytest.raises(ValueError, match="not exact on a TPU"):
+        J.run_device_sweep([])
+    exp = Experiment(mechanisms=["BASE"], seeds=(0,), processes=0,
+                     workloads=[WorkloadConfig(n_jobs=20)], device="jax")
+    with pytest.raises(ValueError, match="not exact on a TPU"):
+        exp.run()

@@ -144,3 +144,54 @@ def test_flash_decode_sweep(B, S, H, K, D, vl, dtype):
     err = jnp.abs(o.astype(jnp.float32) - o2.astype(jnp.float32)).max()
     assert float(err) < tol(dtype) * 10
     assert o.dtype == q.dtype
+
+
+# ------------------------------------------ gradients and left-padding masks
+def test_flash_attention_grad_matches_reference():
+    q, k, v = rnd(2, 128, 4, 32), rnd(2, 128, 2, 32), rnd(2, 128, 2, 32)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) ** 2)
+    g = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, block_q=32, block_kv=64, interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(lambda q, k, v: ref.naive_attention(q, k, v)),
+                     argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        assert float(jnp.abs(a - b).max()) < 1e-4
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+def test_left_padding_is_masked(impl):
+    """Row 1 is padded by 40 slots: its real rows must equal attention
+    over the unpadded sequence, in prefill and in decode."""
+    B, S, H, K, D = 2, 128, 4, 2, 32
+    q, k, v = rnd(B, S, H, D), rnd(B, S, K, D), rnd(B, S, K, D)
+    start = jnp.asarray([0, 40], jnp.int32)
+    if impl == "pallas":
+        o = flash_attention(q, k, v, start, block_q=32, block_kv=64,
+                            interpret=True)
+    else:
+        o = ops.attention(q, k, v, kv_start=start, block_q=32, block_kv=64)
+    o_ref = ref.naive_attention(q[1:, 40:], k[1:, 40:], v[1:, 40:])
+    assert float(jnp.abs(o[1, 40:] - o_ref[0]).max()) < 1e-5
+    assert float(jnp.abs(o[1, :40]).max()) == 0.0        # pad rows: zeros
+    from repro.kernels.flash_decode import flash_decode
+    q1 = rnd(B, 1, H, D)
+    if impl == "pallas":
+        od = flash_decode(q1, k, v, jnp.asarray(100), start, block_kv=32,
+                          interpret=True)
+    else:
+        od = ops.attention(q1, k, v, causal=False,
+                           kv_valid_len=jnp.asarray(100), kv_start=start)
+    od_ref = ref.naive_attention(q1[1:], k[1:, 40:], v[1:, 40:],
+                                 kv_valid_len=jnp.asarray(60))
+    assert float(jnp.abs(od[1] - od_ref[0]).max()) < 1e-5
+
+
+def test_fit_block_and_padded_len():
+    assert ops.fit_block(2304, 512) == 384      # vlm: 2048 text + 256 patches
+    assert ops.fit_block(64, 512) == 64
+    assert ops.fit_block(63, 512) == 0          # no kernel block: jnp path
+    assert ops.padded_len(63) == 64 and ops.padded_len(8) == 16
+    assert ops.fit_block(ops.padded_len(37), 1024) == 48

@@ -185,6 +185,83 @@ def test_serving_engine_batches_and_latency():
     assert all(a.tokens_out == b.tokens_out for a, b in zip(reqs, reqs2))
 
 
+def test_serve_engine_stays_on_its_devices():
+    """An engine handed devices 2-3 keeps its params and cache there, and
+    its left-padded batch answers like each request alone."""
+    out = run_py(SMALL_CFG + """
+import jax, numpy as np
+from repro.models import init_params
+from repro.serving import ServeEngine
+devs = jax.devices()
+eng = ServeEngine(CFG, init_params(jax.random.PRNGKey(0), CFG), max_seq=64,
+                  devices=devs[2:4])
+rng = np.random.default_rng(0)
+prompts = [rng.integers(0, 256, n, dtype=np.int32) for n in (5, 21)]
+with eng._placed():
+    _, cache, _, _, logits = eng._start(prompts)
+where = {d.id for x in jax.tree.leaves((eng.params, cache)) for d in x.devices()}
+print("where", sorted(where))
+alone = [eng.step_logits(p, [])[0] for p in prompts]
+err = max(np.abs(np.asarray(logits[i], np.float32) - alone[i]).max()
+          for i in range(2))
+print("pad_err", err)
+""", devices=4)
+    assert out.split("where")[1].split("\n")[0].strip() == "[2, 3]"
+    assert float(out.split("pad_err")[1].split()[0]) < 1e-4
+
+
+def test_preempt_frees_device_state_and_resumes_bit_identically():
+    out = run_py(SMALL_CFG + """
+import jax, numpy as np, tempfile
+from repro.runtime import ElasticJob
+devs = jax.devices()
+d = tempfile.mkdtemp()
+job = ElasticJob(1, CFG, kind="malleable", batch=8, seq=32,
+                 ckpt_dir=d, ckpt_every=100, seed=0)
+job.start(devs[2:4])
+for _ in range(3): job.step()
+held = jax.tree.leaves(job.state)
+saved = [np.asarray(x) for x in held]
+job.preempt(warning=True)
+print("freed", job.state is None and all(x.is_deleted() for x in held))
+job.resume(devs[0:2])
+back = [np.asarray(x) for x in jax.tree.leaves(job.state)]
+print("identical", all(np.array_equal(a, b) for a, b in zip(saved, back)))
+print("on", sorted({d.id for x in jax.tree.leaves(job.state) for d in x.devices()}))
+nockpt = ElasticJob(2, CFG, kind="rigid", batch=8, seq=32,
+                    ckpt_dir=tempfile.mkdtemp(), ckpt_every=100, seed=0)
+nockpt.start(devs[:2])
+nockpt.step()
+nockpt.preempt(warning=False)    # rigid, no checkpoint yet: state is kept
+print("kept", nockpt.state is not None)
+nockpt.resume(devs[2:4])
+print("resumed_step", nockpt.step_idx)
+""", devices=4)
+    assert "freed True" in out and "identical True" in out
+    assert "on [0, 1]" in out and "kept True" in out
+    assert "resumed_step 1" in out
+
+
+@pytest.mark.parametrize("preset", [False, True])
+def test_compile_cache_dir(preset, tmp_path):
+    from repro import compile_cache
+    preset = str(tmp_path / "cache") if preset else None
+    code = ("import jax; from repro.compile_cache import enable_compile_cache;"
+            "print(enable_compile_cache(), jax.config.jax_compilation_cache_dir)")
+    env = dict(os.environ, PYTHONPATH=SRC)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if preset:
+        env["JAX_COMPILATION_CACHE_DIR"] = preset
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    want = preset or str(compile_cache.DEFAULT_DIR)
+    assert out.stdout.split() == [want, want]
+    assert compile_cache.DEFAULT_DIR.name == ".jax_cache"
+    assert compile_cache.DEFAULT_DIR.parent == \
+        compile_cache.Path(SRC).resolve().parent
+
+
 def test_straggler_monitor():
     from repro.runtime import StragglerMonitor
     mon = StragglerMonitor(threshold=2.0)

@@ -149,6 +149,20 @@ def test_plan_requests_deterministic_and_bounded():
     assert all(8 <= r["prompt_len"] < 64 for r in plan)
 
 
+def test_plan_requests_per_node():
+    od = JobSpec(jid=5, jtype=JobType.ONDEMAND, project="od", submit_time=0.0,
+                 size=1, t_estimate=10.0, t_actual=10.0)
+    assert len(plan_requests(od)) == 1
+    plan = plan_requests(od, per_node=4)
+    assert len(plan) == 4
+    assert plan[0] == plan_requests(od)[0]
+    assert len({r["rid"] for r in plan}) == 4
+    assert len({r["prompt_len"] for r in plan}) == 4     # a ragged batch
+    od3 = JobSpec(jid=5, jtype=JobType.ONDEMAND, project="od",
+                  submit_time=0.0, size=3, t_estimate=10.0, t_actual=10.0)
+    assert len(plan_requests(od3, per_node=4)) == 8      # max_batch caps it
+
+
 # ------------------------------------------------------- core + replay loop
 def test_service_core_decision_stream_matches_offline_reference():
     jobs, n_nodes = _scenario_jobs()
