@@ -24,6 +24,10 @@ launcher).  Event-log timestamps are monotonic seconds since cluster
 construction (never wall clock — they feed latency summaries);
 ``started_wall`` keeps the single wall-clock anchor for humans.
 
+On-demand acquisition and release record the spans ``cluster.acquire_od``
+(the arrival policy's preempts and shrinks) and ``cluster.release_od``
+(``repro.telemetry``), keyed by the cluster's on-demand id.
+
 This module imports nothing from jax: `ElasticJob` is a type-only
 import, so shadow-mode tests drive LiveCluster with duck-typed fakes on
 CPU-only CI (tests/test_live_cluster.py).
@@ -34,6 +38,7 @@ import time
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence
 
+from repro import telemetry
 from repro.core.job import JobType
 from repro.core.policy import ArrivalPolicy, ElasticityPolicy, get_policy
 
@@ -199,6 +204,7 @@ class LiveCluster:
         self.elasticity = elasticity
         self._lease_book: Dict[int, int] = {}   # lender jid -> nodes owed
         self._od_count = 0
+        self._od_of: Dict[int, int] = {}        # node id -> on-demand id
         self.log: List[dict] = []
         self.started_wall = time.time()         # the one wall-clock anchor
         self._t0 = time.monotonic()
@@ -304,46 +310,56 @@ class LiveCluster:
                              f"{len(self.devices)} nodes")
         self._od_count += 1
         od_jid = -self._od_count          # below any real jid
-        if need <= len(self.free):
-            got = [self.free.pop() for _ in range(need)]
-            self._log("od_acquire", od_jid, source="free", nodes=need)
-            return got
-        ops = _LiveOps(self, od_jid, need)
-        if not self.arrival.acquire(ops, od_jid, need - len(self.free)) \
-                or ops.acquired is None:
-            raise RuntimeError(
-                f"cannot vacate {need} nodes "
-                f"(arrival policy {self.arrival.name})")
-        self._log("od_acquire", od_jid, source=self.arrival.name, nodes=need)
-        return ops.acquired
+        with telemetry.span("cluster.acquire_od", key=od_jid, n=need):
+            if need <= len(self.free):
+                got = [self.free.pop() for _ in range(need)]
+                self._log("od_acquire", od_jid, source="free", nodes=need)
+            else:
+                ops = _LiveOps(self, od_jid, need)
+                if not self.arrival.acquire(ops, od_jid,
+                                            need - len(self.free)) \
+                        or ops.acquired is None:
+                    raise RuntimeError(
+                        f"cannot vacate {need} nodes "
+                        f"(arrival policy {self.arrival.name})")
+                got = ops.acquired
+                self._log("od_acquire", od_jid, source=self.arrival.name,
+                          nodes=need)
+        self._od_of.update((i, od_jid) for i in got)
+        return got
 
     def release_ondemand(self, node_ids: List[int]) -> None:
         """On-demand completion: lease repayment first (shrunk lenders
         reclaim their nodes, paper §III-B3 — core mechanics, independent
         of policy), then the elasticity policy absorbs the remainder,
         then the free pool / waiting jobs."""
-        pool = list(node_ids)
-        for jid in list(self._lease_book):
-            if not pool:
-                break
-            info = self.jobs.get(jid)
-            if info is None or info.status != "running":
-                del self._lease_book[jid]
-                continue
-            grow = min(self._lease_book[jid], len(pool),
-                       info.max_nodes - len(info.node_ids))
-            if grow > 0:
-                self._expand(jid, [pool.pop() for _ in range(grow)])
-            if self._lease_book[jid] - grow > 0:
-                self._lease_book[jid] -= grow
-            else:
-                del self._lease_book[jid]
-        if pool:
-            ops = _LiveOps(self, pool=pool)
-            self.elasticity.absorb_release(ops, len(pool))
-            self.free.extend(pool)        # whatever absorb left behind
-            pool = []
-        self._restart_waiting()
+        od_jid = None
+        for i in node_ids:
+            od_jid = self._od_of.pop(i, od_jid)
+        with telemetry.span("cluster.release_od", key=od_jid,
+                            n=len(node_ids)):
+            pool = list(node_ids)
+            for jid in list(self._lease_book):
+                if not pool:
+                    break
+                info = self.jobs.get(jid)
+                if info is None or info.status != "running":
+                    del self._lease_book[jid]
+                    continue
+                grow = min(self._lease_book[jid], len(pool),
+                           info.max_nodes - len(info.node_ids))
+                if grow > 0:
+                    self._expand(jid, [pool.pop() for _ in range(grow)])
+                if self._lease_book[jid] - grow > 0:
+                    self._lease_book[jid] -= grow
+                else:
+                    del self._lease_book[jid]
+            if pool:
+                ops = _LiveOps(self, pool=pool)
+                self.elasticity.absorb_release(ops, len(pool))
+                self.free.extend(pool)        # whatever absorb left behind
+                pool = []
+            self._restart_waiting()
 
     def _on_idle(self) -> None:
         """Post-scheduling elasticity hook: BALANCE-style policies grow

@@ -14,19 +14,26 @@ expansion" + resume):
                         mesh (checkpoint-free elastic resize)
   resume(devices)       start() from the persisted checkpoint
 
-Re-sharding uses jax.device_put with the new mesh's NamedShardings — the
-runtime-measured cost of the paper's "negligible" malleable resize
-assumption (``resize_costs``).
+Re-sharding uses jax.device_put with the new mesh's NamedShardings;
+``resize`` waits for it and returns the time taken — the measured cost of
+the paper's "negligible" malleable resize assumption.
+
+Each operation records its spans (``repro.telemetry``), keyed by the job's
+id: ``train.step`` (``train.batch``, ``train.place``, ``train.dispatch``,
+``train.sync``), ``elastic.preempt`` (the checkpoint's ``ckpt.*`` spans,
+``elastic.free``), ``elastic.resume`` (``elastic.jit``, ``ckpt.load``,
+``ckpt.place``) and ``elastic.resize``.
 """
 from __future__ import annotations
 
-import time
-from typing import List, Optional, Sequence
+import os
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import telemetry
 from repro.models import init_params, set_mesh
 from repro.models.config import ModelConfig
 from repro.sharding import batch_axes, batch_sharding, tree_shardings
@@ -55,7 +62,6 @@ class ElasticJob:
         self.mesh: Optional[Mesh] = None
         self.devices: Sequence = ()
         self.monitor = StragglerMonitor()
-        self.resize_costs: List[float] = []
         self._step_fn = None
 
     # ------------------------------------------------------------------ mesh
@@ -96,52 +102,66 @@ class ElasticJob:
         step = checkpoint.latest_step(self.ckpt_dir)
         if step is None:
             return self.start(devices)
-        self.devices = list(devices)
-        self.mesh = self._build(self.devices)
-        self._jit()
-        self._free()
-        template = jax.eval_shape(self._init_state)
-        self.state = checkpoint.restore(
-            self.ckpt_dir, template, step=step,
-            shardings=tree_shardings(template, self.cfg, self.mesh))
+        with telemetry.span("elastic.resume", key=self.jid) as sp:
+            self.devices = list(devices)
+            self.mesh = self._build(self.devices)
+            with telemetry.span("elastic.jit"):
+                self._jit()
+            self._free()
+            template = jax.eval_shape(self._init_state)
+            self.state = checkpoint.restore(
+                self.ckpt_dir, template, step=step,
+                shardings=tree_shardings(template, self.cfg, self.mesh))
+            sp.n = os.path.getsize(checkpoint.step_file(self.ckpt_dir, step))
         self.step_idx = step
 
     # ------------------------------------------------------------------ step
     def next_batch(self):
         """The next step's synthetic batch, placed on the job's mesh."""
-        batch = synthetic_batch(self.cfg, self.batch, self.seq,
-                                seed=self.seed, step=self.step_idx)
-        return jax.device_put(batch, batch_sharding(batch, self.mesh))
+        with telemetry.span("train.batch"):
+            batch = synthetic_batch(self.cfg, self.batch, self.seq,
+                                    seed=self.seed, step=self.step_idx)
+        with telemetry.span("train.place"):
+            return jax.device_put(batch, batch_sharding(batch, self.mesh))
 
     def step(self) -> dict:
-        t0 = time.perf_counter()
-        batch = self.next_batch()
-        # tracing happens on the first call after (re)jit: the sharding-
-        # constraint mesh context must be THIS job's mesh at that moment
-        set_mesh(self.mesh, batch_axes(self.mesh))
-        with self.mesh:
-            self.state, metrics = self._step_fn(self.state, batch)
-        metrics = {k: float(v) for k, v in metrics.items()}
+        """One train step, ending in the metrics' host sync.  The
+        straggler monitor reads the ``train.step`` span's duration."""
+        tokens = self.batch * (self.seq + (
+            self.cfg.n_patches if self.cfg.family == "vlm" else 0))
+        with telemetry.span("train.step", key=self.jid, n=tokens) as sp:
+            batch = self.next_batch()
+            # tracing happens on the first call after (re)jit: the sharding-
+            # constraint mesh context must be THIS job's mesh at that moment
+            set_mesh(self.mesh, batch_axes(self.mesh))
+            with telemetry.span("train.dispatch"), self.mesh:
+                self.state, metrics = self._step_fn(self.state, batch)
+            with telemetry.span("train.sync"):
+                metrics = {k: float(v) for k, v in metrics.items()}
+        telemetry.count("train.tokens", tokens)
         self.step_idx += 1
-        self.monitor.observe(time.perf_counter() - t0)
+        self.monitor.observe(sp.t1 - sp.t0)
         if self.ckpt_dir and self.step_idx % self.ckpt_every == 0:
             self.checkpoint()
         return metrics
 
-    def checkpoint(self) -> None:
+    def checkpoint(self) -> str:
+        """Write the state at this step; returns the file written."""
         assert self.ckpt_dir is not None
-        checkpoint.save(self.ckpt_dir, self.step_idx, self.state)
+        return checkpoint.save(self.ckpt_dir, self.step_idx, self.state)
 
     # -------------------------------------------------------------- preempt
     def preempt(self, warning: bool = True) -> None:
         """warning=True is the 2-minute-warning path (malleable): snapshot
         the exact current step.  Rigid jobs lose work since the last
         periodic checkpoint (paper §III-A)."""
-        if self.ckpt_dir is not None and (warning or self.kind == "malleable"):
-            self.checkpoint()
-        if self.ckpt_dir is not None and \
-                checkpoint.latest_step(self.ckpt_dir) is not None:
-            self._free()      # resume restores; the nodes go to the next job
+        with telemetry.span("elastic.preempt", key=self.jid) as sp:
+            if self.ckpt_dir is not None and \
+                    (warning or self.kind == "malleable"):
+                sp.n = os.path.getsize(self.checkpoint())
+            if self.ckpt_dir is not None and \
+                    checkpoint.latest_step(self.ckpt_dir) is not None:
+                self._free()  # resume restores; the nodes go to the next job
         self.mesh = None
         self._step_fn = None
         self.devices = ()
@@ -150,22 +170,24 @@ class ElasticJob:
         """Release the train state's device buffers now, not whenever the
         last reference dies."""
         if self.state is not None:
-            for leaf in jax.tree.leaves(self.state):
-                leaf.delete()
+            with telemetry.span("elastic.free"):
+                for leaf in jax.tree.leaves(self.state):
+                    leaf.delete()
         self.state = None
 
     # -------------------------------------------------------- shrink/expand
     def resize(self, devices: Sequence) -> float:
         """Checkpoint-free elastic resize onto a new device set.  Returns
-        the wall-clock resharding cost in seconds."""
-        t0 = time.perf_counter()
-        self.devices = list(devices)
-        self.mesh = self._build(self.devices)
-        self._jit()
-        self._reshard()
-        dt = time.perf_counter() - t0
-        self.resize_costs.append(dt)
-        return dt
+        the seconds it took, the resharded state on its devices (the
+        ``elastic.resize`` span); the step recompiles on its next call."""
+        with telemetry.span("elastic.resize", key=self.jid) as sp:
+            self.devices = list(devices)
+            self.mesh = self._build(self.devices)
+            self._jit()
+            self._reshard()
+            jax.block_until_ready(self.state)
+            sp.n = sum(x.nbytes for x in jax.tree.leaves(self.state))
+        return sp.t1 - sp.t0
 
     def _reshard(self) -> None:
         sh = tree_shardings(self.state, self.cfg, self.mesh)

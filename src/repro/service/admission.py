@@ -30,11 +30,16 @@ producer outruns the daemon is a policy choice (``backpressure``):
 
 Shed / rejected / blocked events are counted in :attr:`counts` and
 surfaced in the ShadowReport for live runs.
+
+Each put stamps ``time.monotonic()`` against the spec's jid;
+``LiveClusterLauncher.start_job`` takes the stamp (:func:`put_time`) and
+records the ``admission.wait`` row (``repro.telemetry``) up to the start.
 """
 from __future__ import annotations
 
 import itertools
 import threading
+import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -42,6 +47,16 @@ from repro.core.job import JobSpec, JobType, NoticeKind
 
 #: valid values for ``AdmissionQueue(backpressure=...)``
 BACKPRESSURE_POLICIES = ("block", "shed-oldest-inference", "reject")
+
+#: jid -> monotonic time of its put, until the launcher starts it; the
+#: oldest go first past _PUT_TIMES_MAX (jobs no live launcher starts)
+_put_times: Dict[int, float] = {}
+_PUT_TIMES_MAX = 1 << 16
+
+
+def put_time(jid: int) -> Optional[float]:
+    """Take the monotonic time at which ``jid`` was put on a queue."""
+    return _put_times.pop(jid, None)
 
 
 class AdmissionRejected(RuntimeError):
@@ -116,6 +131,9 @@ class AdmissionQueue:
             self._q.append(spec)
             self.n_submitted += 1
             self.counts["submitted"] += 1
+            if len(_put_times) >= _PUT_TIMES_MAX:
+                _put_times.pop(next(iter(_put_times)), None)
+            _put_times[spec.jid] = time.monotonic()
         return spec
 
     def drain(self) -> List[JobSpec]:

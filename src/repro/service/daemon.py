@@ -13,8 +13,9 @@ Two loops share the core:
   Scenario's jobs arrive as live traffic at ``speed`` sim-seconds per
   wall-second (``inf`` = as fast as decisions can be made, the CI
   mode).  Each iteration sleeps until the next event's sim time, steps
-  the core through exactly that event batch under a perf_counter, and
-  appends the drained decisions with the batch latency attached.
+  the core through exactly that event batch under the
+  ``service.event_batch`` span (``repro.telemetry``), and appends the
+  drained decisions with the batch latency attached.
 * :meth:`SchedulerService.run_live` — jobs arrive through an
   :class:`~repro.service.admission.AdmissionQueue` instead of a trace;
   the loop polls admissions between batches and exits when the queue
@@ -40,6 +41,7 @@ import time
 from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
+from repro import telemetry
 from repro.core.job import JobSpec
 from repro.core.simulator import JobRecord, SimConfig, Simulator
 
@@ -89,6 +91,8 @@ class ShadowReport:
     slo: Dict                     # SloReport.as_dict()
     launcher_counts: Optional[Dict[str, int]] = None
     admission_counts: Optional[Dict[str, int]] = None   # live mode only
+    #: the process's spans and counters (``repro.telemetry.summary()``)
+    telemetry: Optional[Dict] = None
 
     def as_dict(self) -> dict:
         return dict(self.__dict__)
@@ -147,9 +151,9 @@ class SchedulerService:
         """Process one event batch under the latency meter and log the
         decisions it produced (log I/O stays outside the meter: the SLO
         bounds scheduling latency, not disk flushes)."""
-        t0 = time.perf_counter()
-        self.core.step_until(t_next)
-        lat_ms = (time.perf_counter() - t0) * 1e3
+        with telemetry.span("service.event_batch") as sp:
+            self.core.step_until(t_next)
+        lat_ms = (sp.t1 - sp.t0) * 1e3
         self.monitor.add_decision_latency(lat_ms)
         for d in self.core.drain_decisions():
             self.log.append(d, latency_ms=lat_ms)
@@ -218,7 +222,8 @@ class SchedulerService:
             wall_s=round(self.wall_s, 3),
             latency=self.log.latency_summary(), slo=slo.as_dict(),
             launcher_counts=dict(counts) if counts is not None else None,
-            admission_counts=dict(adm.counts) if adm is not None else None)
+            admission_counts=dict(adm.counts) if adm is not None else None,
+            telemetry=telemetry.summary())
 
     # -------------------------------------------------------------- recovery
     @classmethod

@@ -29,8 +29,11 @@ import warnings
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
+from repro import telemetry
 from repro.core.job import JobSpec, JobType
 from repro.core.simulator import JobRecord
+
+from .admission import put_time
 
 
 class ShadowLaunchError(RuntimeError):
@@ -315,20 +318,27 @@ class LiveClusterLauncher(Launcher):
         self.served: List[object] = []
 
     def start_job(self, job: JobSpec, size: int) -> None:
-        if job.jtype is JobType.ONDEMAND:
-            nodes = self.cluster.acquire_for_ondemand(size)
-            self.od_nodes[job.jid] = nodes
-            if self.serve_fn is not None:
-                devices = [self.cluster.devices[i] for i in nodes]
-                self.served.append(self.serve_fn(job, devices))
-            return
-        if job.jid in self.infos:       # restart after preemption
-            return                      # cluster resumes it on free nodes
-        ej = self.job_factory(job)
-        n_min = job.n_min if job.jtype is JobType.MALLEABLE else size
-        self.infos[job.jid] = self.cluster.submit(
-            ej, min_nodes=max(1, n_min), max_nodes=size,
-            target_steps=self.target_steps)
+        """Records ``launch.start_job``, and ``admission.wait`` from the
+        job's admission (``AdmissionQueue.put``) to here."""
+        t_put = put_time(job.jid)
+        with telemetry.span("launch.start_job", key=job.jid, n=size) as sp:
+            if t_put is not None:
+                telemetry.record("admission.wait", t_put, sp.t0,
+                                 key=job.jid)
+            if job.jtype is JobType.ONDEMAND:
+                nodes = self.cluster.acquire_for_ondemand(size)
+                self.od_nodes[job.jid] = nodes
+                if self.serve_fn is not None:
+                    devices = [self.cluster.devices[i] for i in nodes]
+                    self.served.append(self.serve_fn(job, devices))
+                return
+            if job.jid in self.infos:       # restart after preemption
+                return                      # cluster resumes it on free nodes
+            ej = self.job_factory(job)
+            n_min = job.n_min if job.jtype is JobType.MALLEABLE else size
+            self.infos[job.jid] = self.cluster.submit(
+                ej, min_nodes=max(1, n_min), max_nodes=size,
+                target_steps=self.target_steps)
 
     def finish(self, rec: JobRecord) -> None:
         nodes = self.od_nodes.pop(rec.job.jid, None)
@@ -336,7 +346,8 @@ class LiveClusterLauncher(Launcher):
             self.cluster.release_ondemand(nodes)
 
     def tick(self) -> None:
-        self.cluster.step_all(self.steps_per_tick)
+        with telemetry.span("launch.tick"):
+            self.cluster.step_all(self.steps_per_tick)
 
     def close(self) -> None:
         for jid, nodes in list(self.od_nodes.items()):

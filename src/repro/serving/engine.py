@@ -18,6 +18,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
 from repro.kernels import ops as kops
 from repro.models import decode_step, dist, prefill
 from repro.models.config import ModelConfig
@@ -85,6 +86,7 @@ class ServeEngine:
                              ("data", "model"))
             params = jax.device_put(params, NamedSharding(self.mesh, P()))
         self.params = params
+        self._n_batches = 0
         self._prefill = jax.jit(
             lambda p, t, st: prefill(p, t, cfg, kv_start=st))
         self._decode = jax.jit(
@@ -125,36 +127,55 @@ class ServeEngine:
         return next_tok, cache, kv_start, S, logits
 
     def serve_batch(self, requests: List[Request]) -> List[Request]:
-        """Run a padded batch of requests to completion."""
-        with self._placed():
-            next_tok, cache, kv_start, S, _ = self._start(
-                [r.prompt for r in requests])
-            toks_np = np.asarray(next_tok)          # waits for the device
-            now = time.monotonic()
-            live = np.ones((len(requests),), bool)
+        """Run a padded batch of requests to completion.  Records the
+        spans ``serve.batch``, ``serve.prefill`` and one
+        ``serve.decode_step`` per step, keyed by the batch's number."""
+        self._n_batches += 1
+        B = len(requests)
+        with self._placed(), telemetry.span(
+                "serve.batch", key=self._n_batches, n=B):
+            n_prompt = sum(len(r.prompt) for r in requests)
+            with telemetry.span("serve.prefill", n=n_prompt) as pre:
+                next_tok, cache, kv_start, S, _ = self._start(
+                    [r.prompt for r in requests])
+                with telemetry.span("serve.token_sync", n=B):
+                    toks_np = np.asarray(next_tok)  # waits for the device
             for i, r in enumerate(requests):
-                r.first_token_at = now
+                r.first_token_at = pre.t1
                 r.tokens_out.append(int(toks_np[i]))
-            n_steps = max(r.max_new_tokens for r in requests)
-            for step in range(1, n_steps):
+            telemetry.count("serve.prompt_tokens", n_prompt)
+            telemetry.count("serve.padded_tokens", B * S)
+            live = np.ones((B,), bool)
+            n_live, n_out, n_steps = B, B, 0
+            for step in range(1, max(r.max_new_tokens for r in requests)):
                 pos = S + step - 1
                 if pos >= self.max_seq:
                     break
-                logits, cache = self._decode(self.params, cache,
-                                             next_tok[:, None], pos, kv_start)
-                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                toks_np = np.asarray(next_tok)
-                for i, r in enumerate(requests):
-                    if not live[i]:
-                        continue
-                    r.tokens_out.append(int(toks_np[i]))
-                    if len(r.tokens_out) >= r.max_new_tokens or \
-                            (self.eos_id is not None
-                             and toks_np[i] == self.eos_id):
-                        live[i] = False
-                        r.done_at = time.monotonic()
-                if not live.any():
+                n_steps += 1
+                with telemetry.span("serve.decode_step", n=n_live):
+                    with telemetry.span("serve.dispatch"):
+                        logits, cache = self._decode(
+                            self.params, cache, next_tok[:, None], pos,
+                            kv_start)
+                        next_tok = jnp.argmax(logits, axis=-1).astype(
+                            jnp.int32)
+                    with telemetry.span("serve.token_sync", n=B):
+                        toks_np = np.asarray(next_tok)
+                    for i, r in enumerate(requests):
+                        if not live[i]:
+                            continue
+                        r.tokens_out.append(int(toks_np[i]))
+                        n_out += 1
+                        if len(r.tokens_out) >= r.max_new_tokens or \
+                                (self.eos_id is not None
+                                 and toks_np[i] == self.eos_id):
+                            live[i] = False
+                            n_live -= 1
+                            r.done_at = time.monotonic()
+                if not n_live:
                     break
+            telemetry.count("serve.tokens_out", n_out)
+            telemetry.count("serve.decode_slots", B * n_steps)
         now = time.monotonic()
         for r in requests:
             r.done_at = r.done_at or now

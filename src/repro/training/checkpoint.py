@@ -8,6 +8,12 @@ scheduler's preempt/resume relies on.
 
 A lightweight manifest (latest.txt) gives atomic "latest checkpoint"
 semantics: write npz -> fsync -> update manifest.
+
+Spans (``repro.telemetry``): a save records ``ckpt.device_get`` (the
+state to host memory), ``ckpt.write`` (``np.savez``) and ``ckpt.fsync``
+(the file's, then the manifest's); a restore records ``ckpt.load`` (the
+file to host memory) and ``ckpt.place`` (the arrays handed to their
+devices, not awaited).  Each counts bytes.
 """
 from __future__ import annotations
 
@@ -18,6 +24,8 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+
+from repro import telemetry
 
 
 def _flatten(tree) -> dict:
@@ -32,19 +40,29 @@ def _flatten(tree) -> dict:
     return flat
 
 
+def step_file(path: str, step: int) -> str:
+    """The file that holds step ``step``'s checkpoint under ``path``."""
+    return os.path.join(path, f"step_{step:08d}.npz")
+
+
 def save(path: str, step: int, tree: Any) -> str:
     """Write `tree` to <path>/step_<n>.npz atomically; returns file path."""
     os.makedirs(path, exist_ok=True)
-    fname = os.path.join(path, f"step_{step:08d}.npz")
-    flat = _flatten(tree)
+    fname = step_file(path, step)
+    with telemetry.span("ckpt.device_get") as sp:
+        flat = _flatten(tree)
+        sp.n = sum(a.nbytes for a in flat.values())
     with tempfile.NamedTemporaryFile(dir=path, delete=False) as tmp:
-        np.savez(tmp, **flat)
-        tmp.flush()
-        os.fsync(tmp.fileno())
+        with telemetry.span("ckpt.write", n=sp.n):
+            np.savez(tmp, **flat)
+        with telemetry.span("ckpt.fsync"):
+            tmp.flush()
+            os.fsync(tmp.fileno())
         tmpname = tmp.name
     os.replace(tmpname, fname)
     manifest = os.path.join(path, "latest.txt")
-    with tempfile.NamedTemporaryFile("w", dir=path, delete=False) as tmp:
+    with telemetry.span("ckpt.fsync"), \
+            tempfile.NamedTemporaryFile("w", dir=path, delete=False) as tmp:
         tmp.write(f"{step}\n{fname}\n")
         tmp.flush()
         os.fsync(tmp.fileno())
@@ -72,21 +90,23 @@ def restore(path: str, template: Any, step: Optional[int] = None,
         step = latest_step(path)
         if step is None:
             raise FileNotFoundError(f"no checkpoint under {path}")
-    fname = os.path.join(path, f"step_{step:08d}.npz")
-    data = np.load(fname)
     leaves_p, treedef = jax.tree_util.tree_flatten_with_path(template)
     shard_leaves = (jax.tree_util.tree_leaves(shardings)
                     if shardings is not None else [None] * len(leaves_p))
     import ml_dtypes
-    out = []
-    for (pth, leaf), sh in zip(leaves_p, shard_leaves):
-        key = "/".join(str(p) for p in pth)
-        if key + ".bf16" in data:
-            arr = np.asarray(data[key + ".bf16"]).view(ml_dtypes.bfloat16)
-        else:
-            arr = np.asarray(data[key])
-        if sh is not None:
-            out.append(jax.device_put(arr, sh))
-        else:
-            out.append(jax.numpy.asarray(arr, dtype=leaf.dtype))
+    arrs = []
+    with telemetry.span("ckpt.load") as sp, \
+            np.load(step_file(path, step)) as data:
+        for pth, _ in leaves_p:
+            key = "/".join(str(p) for p in pth)
+            if key + ".bf16" in data:
+                arrs.append(np.asarray(data[key + ".bf16"]).view(
+                    ml_dtypes.bfloat16))
+            else:
+                arrs.append(np.asarray(data[key]))
+        sp.n = sum(a.nbytes for a in arrs)
+    with telemetry.span("ckpt.place", n=sp.n):
+        out = [jax.device_put(arr, sh) if sh is not None
+               else jax.numpy.asarray(arr, dtype=leaf.dtype)
+               for arr, (_, leaf), sh in zip(arrs, leaves_p, shard_leaves)]
     return jax.tree_util.tree_unflatten(treedef, out)
