@@ -52,16 +52,19 @@ def _sds(sharding, shape, dtype=jnp.bfloat16):
 
 @pytest.mark.parametrize("B,S", [(1, 2048),   # text alone
                                  (1, 2304),   # 2048 text + 256 patches
-                                 (4, 64)])    # a padded short prompt batch
+                                 (4, 64),     # a padded short prompt batch
+                                 (8, 2048),   # serving's largest prefill
+                                 (1, 16384)])  # 272 block pairs a head
 def test_flash_attention_compiles(one_chip, B, S):
     q, kv = _sds(one_chip, (B, S, H, D)), _sds(one_chip, (B, S, K, D))
     start = _sds(one_chip, (B,), jnp.int32)
     fwd = jax.jit(lambda q, k, v, s: flash_attention(q, k, v, s))
     assert "tpu_custom_call" in fwd.lower(q, kv, kv, start).compile().as_text()
     grad = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(flash_attention(q, k, v).astype(jnp.float32)),
+        lambda q, k, v, s: jnp.sum(
+            flash_attention(q, k, v, s).astype(jnp.float32)),
         argnums=(0, 1, 2)))
-    grad.lower(q, kv, kv).compile()
+    grad.lower(q, kv, kv, start).compile()
 
 
 def test_flash_decode_compiles(one_chip):

@@ -26,6 +26,7 @@ def tol(dt):
     (2, 256, 8, 2, 64),     # GQA 4:1
     (1, 256, 4, 1, 64),     # MQA
     (1, 512, 2, 2, 128),    # MXU-aligned head dim
+    (1, 288, 14, 2, 64),    # GQA 7:1, a block that is no power of two
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
@@ -159,6 +160,118 @@ def test_flash_attention_grad_matches_reference():
                      argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g, g_ref):
         assert float(jnp.abs(a - b).max()) < 1e-4
+
+
+@pytest.mark.parametrize("S,block_q,block_kv,dtype", [
+    (288, 96, 96, jnp.float32),      # three chunks of 96, one diagonal each
+    (288, 96, 48, jnp.float32),      # chunks of 48: two cross each diagonal
+    (288, 96, 144, jnp.bfloat16),    # a chunk wider than the q block
+])
+def test_flash_attention_grad_chunks(S, block_q, block_kv, dtype):
+    """Forward and gradient at GQA 7:1, d_head 64, over several kv
+    chunks whose size is not a power of two."""
+    q, k, v = rnd(1, S, 14, 64, dt=dtype), rnd(1, S, 2, 64, dt=dtype), \
+        rnd(1, S, 2, 64, dt=dtype)
+    ct = rnd(1, S, 14, 64)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * ct)
+    flash = lambda q, k, v: flash_attention(              # noqa: E731
+        q, k, v, block_q=block_q, block_kv=block_kv, interpret=True)
+    o = flash(q, k, v).astype(jnp.float32)
+    o_ref = ref.naive_attention(q, k, v).astype(jnp.float32)
+    assert float(jnp.abs(o - o_ref).max()) < tol(dtype) * 10
+    g = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(ref.naive_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        scale = float(jnp.abs(b).max())
+        assert float(jnp.abs(a - b).max()) < tol(dtype) * 10 * scale
+
+
+@pytest.mark.parametrize("start", [40, 96, 150, 192])
+def test_flash_attention_kv_start_chunks(start):
+    """A kv_start inside a chunk of 96 (40, 150) and on a chunk's edge
+    (96, 192): the real rows' outputs and gradients equal attention over
+    the unpadded sequence; the pad rows give zeros and take no
+    gradient."""
+    S, H, K, D = 288, 14, 2, 64
+    q, k, v = rnd(2, S, H, D), rnd(2, S, K, D), rnd(2, S, K, D)
+    ct = rnd(2, S, H, D)
+    starts = jnp.asarray([0, start], jnp.int32)
+
+    def flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, starts, block_q=96,
+                                       block_kv=96, interpret=True)[1, start:]
+                       * ct[1, start:])
+
+    def naive(q, k, v):
+        return jnp.sum(ref.naive_attention(q, k, v)[0] * ct[1, start:])
+    o = flash_attention(q, k, v, starts, block_q=96, block_kv=96,
+                        interpret=True)
+    o_ref = ref.naive_attention(q[1:, start:], k[1:, start:], v[1:, start:])
+    assert float(jnp.abs(o[1, start:] - o_ref[0]).max()) < 1e-5
+    assert float(jnp.abs(o[1, :start]).max()) == 0.0
+    assert float(jnp.abs(o[0] - ref.naive_attention(q[:1], k[:1], v[:1])[0]
+                         ).max()) < 1e-5
+    g = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(naive, argnums=(0, 1, 2))(q[1:, start:], k[1:, start:],
+                                               v[1:, start:])
+    for a, b in zip(g, g_ref):
+        assert float(jnp.abs(a[1, start:] - b[0]).max()) < 1e-4
+        assert float(jnp.abs(a[1, :start]).max()) == 0.0
+        assert float(jnp.abs(a[0]).max()) == 0.0
+
+
+def test_flash_attention_long_padded():
+    """A long sequence (eight q blocks of 512, four chunks of 1,024, 20
+    live pairs a head) whose kv_start lies past its first chunk and cuts
+    the second: the real rows' outputs and gradients equal attention
+    over the unpadded sequence."""
+    S, D, start = 4096, 128, 1100
+    q, k, v = rnd(1, S, 2, D), rnd(1, S, 1, D), rnd(1, S, 1, D)
+    starts = jnp.asarray([start], jnp.int32)
+    ct = rnd(1, S, 2, D)
+
+    def flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, starts, interpret=True)
+                       [0, start:] * ct[0, start:])
+
+    def naive(q, k, v):
+        return jnp.sum(ref.naive_attention(q, k, v)[0] * ct[0, start:])
+    o = flash_attention(q, k, v, starts, interpret=True)
+    o_ref = ref.naive_attention(q[:, start:], k[:, start:], v[:, start:])
+    assert float(jnp.abs(o[0, start:] - o_ref[0]).max()) < 1e-4
+    g = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(naive, argnums=(0, 1, 2))(q[:, start:], k[:, start:],
+                                               v[:, start:])
+    for a, b in zip(g, g_ref):
+        assert float(jnp.abs(a[0, start:] - b[0]).max()) < 1e-4
+
+
+@pytest.mark.parametrize("B,S,block_q,block_kv,live,masked", [
+    (1, 2304, 512, 1024, 12, 6),     # train_steady: 6 q blocks, 3 chunks
+    (2, 288, 96, 96, 6, 3),          # 3 q blocks, 3 chunks of the same size
+    (1, 288, 96, 48, 12, 6),         # 3 q blocks, 6 chunks of half the size
+])
+def test_flash_attention_chunk_counters(B, S, block_q, block_kv, live, masked):
+    """kernels.attn_chunks / kernels.attn_chunks_masked count the live
+    (q block, kv chunk) pairs of a traced call and those the diagonal
+    crosses: ``live`` and ``masked`` per head and batch row."""
+    from repro import telemetry
+    H, K, D = 14, 2, 64
+    jax.clear_caches()                       # trace afresh: counts once
+    before = telemetry.counters()
+    jax.eval_shape(lambda q, k, v: flash_attention(
+        q, k, v, block_q=block_q, block_kv=block_kv, interpret=True),
+        jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((B, S, K, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((B, S, K, D), jnp.bfloat16))
+    after = telemetry.counters()
+    delta = {c: after.get(c, 0) - before.get(c, 0)
+             for c in ("kernels.attn_chunks", "kernels.attn_chunks_masked")}
+    assert delta == {"kernels.attn_chunks": B * H * live,
+                     "kernels.attn_chunks_masked": B * H * masked}
 
 
 @pytest.mark.parametrize("impl", ["pallas", "jnp"])
