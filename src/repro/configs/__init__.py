@@ -4,7 +4,7 @@ from importlib import import_module
 ARCH_IDS = (
     "xlstm_350m", "yi_9b", "llama3_8b", "chatglm3_6b", "granite_34b",
     "deepseek_v2_236b", "olmoe_1b_7b", "zamba2_1p2b", "internvl2_1b",
-    "seamless_m4t_medium",
+    "seamless_m4t_medium", "deepseek_v2_lite",
 )
 
 # public --arch names (dashes) -> module names

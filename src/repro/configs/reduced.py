@@ -30,14 +30,14 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
         param_dtype="float32", compute_dtype="float32",
     )
     if cfg.moe:
-        # capacity_factor high enough that smoke tests never drop tokens
-        # (dropping makes prefill/forward outputs differ by construction)
+        # a config that holds a share of its experts keeps holding one
         kw["moe"] = dataclasses.replace(
-            cfg.moe, n_experts=8, top_k=2, d_expert=64, capacity_factor=4.0,
-            d_first_dense=256 if cfg.moe.first_dense else 0)
+            cfg.moe, n_experts=8, top_k=2, d_expert=64,
+            n_held=4 if cfg.moe.n_held else 0)
     if cfg.mla:
         kw["mla"] = dataclasses.replace(
-            cfg.mla, kv_lora=64, q_lora=96, d_nope=32, d_rope=16, d_v=32)
+            cfg.mla, kv_lora=64, q_lora=96 if cfg.mla.q_lora else None,
+            d_nope=32, d_rope=16, d_v=32)
     if cfg.ssm:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=16, d_head=32,
                                         chunk=32)

@@ -1,10 +1,12 @@
 """Flash attention as Pallas TPU kernels: forward and backward.
 
-Layout: the wrapper views q as (B, H, S, D) and k/v as (B, K, S, D), so
-every block is (1, 1, rows, D).  Its last two dimensions are a multiple
-of 16 and the full head dim, which the TPU tiling accepts for any head
-count and d_head (a head axis blocked at 1 in the second-to-last
-position is refused by the compiler).  GQA is handled in the k/v
+Layout: the wrapper views q as (B, H, S, D), k as (B, K, S, D) and v as
+(B, K, S, Dv), so every block is (1, 1, rows, D or Dv).  Its last two
+dimensions are a multiple of 16 and the full head dim, which the TPU
+tiling accepts for any head count and d_head (a head axis blocked at 1
+in the second-to-last position is refused by the compiler).  The value
+width Dv (the output's and dv's) may differ from the q/k width D, as in
+latent attention (D 192, Dv 128).  GQA is handled in the k/v
 index_map (h -> h // G), so the head broadcast is never materialized in
 HBM.
 
@@ -203,9 +205,10 @@ def _fwd_kernel(tab_ref, start_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _specs(G, T, bq, bc, D):
-    """Block specs over grid (b, h, pair): the pair's q block, its kv
-    chunk (a chunk wholly before ``kv_start`` maps to the first live one,
-    so its step fetches nothing new) and the q block's row stats."""
+    """Block specs over grid (b, h, pair) of width D: the pair's q block,
+    its kv chunk (a chunk wholly before ``kv_start`` maps to the first
+    live one, so its step fetches nothing new) and the q block's row
+    stats."""
     q = pl.BlockSpec((1, 1, bq, D), lambda b, h, t, tab, st: (b, h, tab[t], 0))
     kv = pl.BlockSpec((1, 1, bc, D), lambda b, h, t, tab, st: (
         b, h // G, jnp.maximum(tab[T + t], st[b] // bc), 0))
@@ -215,22 +218,23 @@ def _specs(G, T, bq, bc, D):
 
 def _fwd(q, k, v, kv_start, causal, scale, bq, bc, interpret):
     B, H, Sq, D = q.shape
-    K, Skv = k.shape[1], k.shape[2]
+    K, Skv, Dv = k.shape[1], k.shape[2], v.shape[3]
     offset = Skv - Sq
     tab = _pairs(Sq // bq, bq, Skv // bc, bc, offset, causal, False)
     T = tab.shape[1]
     q_spec, kv_spec, row_spec = _specs(H // K, T, bq, bc, D)
+    o_spec, v_spec, _ = _specs(H // K, T, bq, bc, Dv)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
                           bc=bc, offset=offset, T=T),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B, H, T),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec, row_spec],
-            scratch_shapes=[pltpu.VMEM((bq, D), _F32),
+            in_specs=[q_spec, kv_spec, v_spec],
+            out_specs=[o_spec, row_spec],
+            scratch_shapes=[pltpu.VMEM((bq, Dv), _F32),
                             pltpu.VMEM((bq, _LANES), _F32),
                             pltpu.VMEM((bq, _LANES), _F32)]),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B, H, Sq, 1), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -301,7 +305,7 @@ def _dkv_kernel(tab_ref, start_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_calls(q, k, v, kv_start, o, lse, do, causal, scale, bq, bc,
                interpret):
     B, H, Sq, D = q.shape
-    K, Skv = k.shape[1], k.shape[2]
+    K, Skv, Dv = k.shape[1], k.shape[2], v.shape[3]
     G, nq, offset = H // K, Sq // bq, Skv - Sq
     delta = jnp.sum(do.astype(_F32) * o.astype(_F32), axis=-1, keepdims=True)
     params = pltpu.CompilerParams(
@@ -312,11 +316,12 @@ def _bwd_calls(q, k, v, kv_start, o, lse, do, causal, scale, bq, bc,
     tab = _pairs(nq, bq, Skv // bc, bc, offset, causal, False)
     T = tab.shape[1]
     q_spec, kv_spec, row_spec = _specs(G, T, bq, bc, D)
+    o_spec, v_spec, _ = _specs(G, T, bq, bc, Dv)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, T=T, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B, H, T),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, kv_spec, v_spec, o_spec, row_spec, row_spec],
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((bq, D), _F32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -327,28 +332,34 @@ def _bwd_calls(q, k, v, kv_start, o, lse, do, causal, scale, bq, bc,
     # dk/dv per query head: pairs kv-major, the q side's row stats resident
     tab = _pairs(nq, bq, Skv // bc, bc, offset, causal, True)
     T = tab.shape[1]
-    q_spec = pl.BlockSpec((1, 1, bq, D),
-                          lambda b, h, t, tab, st: (b, h, tab[t], 0))
-    kv_spec = pl.BlockSpec((1, 1, bc, D),
-                           lambda b, h, t, tab, st: (b, h // G, tab[T + t], 0))
-    out_spec = pl.BlockSpec((1, 1, bc, D),
-                            lambda b, h, t, tab, st: (b, h, tab[T + t], 0))
+
+    def specs(width):
+        q = pl.BlockSpec((1, 1, bq, width),
+                         lambda b, h, t, tab, st: (b, h, tab[t], 0))
+        kv = pl.BlockSpec((1, 1, bc, width),
+                          lambda b, h, t, tab, st: (b, h // G, tab[T + t], 0))
+        out = pl.BlockSpec((1, 1, bc, width),
+                           lambda b, h, t, tab, st: (b, h, tab[T + t], 0))
+        return q, kv, out
+    q_spec, kv_spec, dk_spec = specs(D)
+    o_spec, v_spec, dv_spec = specs(Dv)
     row_spec = pl.BlockSpec((1, 1, nq, bq), lambda b, h, t, tab, st: (b, h, 0, 0))
     tiles = lambda x: x.reshape(B, H, nq, bq)               # noqa: E731
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, T=T, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B, H, T),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            out_specs=[out_spec, out_spec],
+            in_specs=[q_spec, kv_spec, v_spec, o_spec, row_spec, row_spec],
+            out_specs=[dk_spec, dv_spec],
             scratch_shapes=[pltpu.VMEM((bc, D), _F32),
-                            pltpu.VMEM((bc, D), _F32)]),
-        out_shape=[jax.ShapeDtypeStruct((B, H, Skv, D), _F32)] * 2,
+                            pltpu.VMEM((bc, Dv), _F32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, Skv, D), _F32),
+                   jax.ShapeDtypeStruct((B, H, Skv, Dv), _F32)],
         compiler_params=params, interpret=interpret,
         name="flash_attention_dkv",
     )(jnp.asarray(tab.reshape(-1)), kv_start, q, k, v, do, tiles(lse),
       tiles(delta))
-    group_sum = lambda x: x.reshape(B, K, G, Skv, D).sum(2)   # noqa: E731
+    group_sum = lambda x: x.reshape(B, K, G, Skv, -1).sum(2)  # noqa: E731
     return dq, group_sum(dk).astype(k.dtype), group_sum(dv).astype(v.dtype)
 
 
@@ -377,7 +388,8 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 def flash_attention(q, k, v, kv_start=None, *, causal: bool = True,
                     scale=None, block_q: int = 512, block_kv: int = 1024,
                     interpret: bool = False):
-    """q: (B, Sq, H, D); k/v: (B, Skv, K, D) with H % K == 0.
+    """q: (B, Sq, H, D); k: (B, Skv, K, D); v: (B, Skv, K, Dv) with
+    H % K == 0.  Returns (B, Sq, H, Dv).
 
     ``block_q``/``block_kv`` are upper bounds: each is lowered to the
     largest multiple of 16 that divides its length (:func:`fit_block`);

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
+
 # "jnp" | "pallas" | "interpret" (Pallas kernels in interpret mode, for
 # CPU rehearsals of the TPU path) | None=auto
 _BACKEND_OVERRIDE: Optional[str] = None
@@ -29,6 +31,10 @@ _BACKEND_OVERRIDE: Optional[str] = None
 #: the bf16 sublane tile: a Pallas block's second-to-last dim must be a
 #: multiple of it (or the whole axis)
 SEQ_ALIGN = 16
+
+#: the largest row tile of a grouped product: each expert's rows start
+#: on a tile (see :func:`gmm_tile`)
+GMM_TILE = 256
 
 #: (attention mode, implementation) -> times traced; read by callers that
 #: must show which implementation ran (e.g. chip_smoke.py)
@@ -270,6 +276,34 @@ def _per_shard(kernel, args, batched):
                   for a in args)
     return jax.shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=bspec,
                          check_vma=False)(*args)
+
+
+def gmm_tile(n_rows: int, n_groups: int) -> int:
+    """Row tile of a grouped product over ``n_rows`` assignments to
+    ``n_groups`` experts: the largest power of two from
+    :data:`SEQ_ALIGN` to :data:`GMM_TILE` whose padding, up to a tile a
+    group, stays within ``n_rows`` (a decode step's few rows take small
+    tiles, a training step's many rows large ones)."""
+    tm = GMM_TILE
+    while tm > SEQ_ALIGN and n_groups * tm > n_rows:
+        tm //= 2
+    return tm
+
+
+def grouped_matmul(lhs, rhs, counts, tm: int):
+    """Each expert's rows of ``lhs`` (M, K) times its matrix of ``rhs``
+    (E, K, N).  Expert ``e``'s ``counts[e]`` rows come after the groups
+    before it, each group starting on a tile of ``tm`` rows; rows after
+    the last group are not computed (the Pallas kernel leaves them
+    unwritten, ``ragged_dot`` zero).  Differentiable."""
+    telemetry.count("kernels.moe_gmm")
+    if _use_pallas():
+        from . import moe_gmm
+        traced_impls[("grouped", "pallas")] += 1
+        return moe_gmm.grouped_matmul(lhs, rhs, counts, tm, _interpret())
+    traced_impls[("grouped", "jnp")] += 1
+    sizes = -(-counts // tm) * tm
+    return jax.lax.ragged_dot(lhs, rhs, sizes.astype(jnp.int32))
 
 
 def _is_pow2(n: int) -> bool:

@@ -13,7 +13,8 @@ def naive_attention(q, k, v, *, causal: bool = True,
                     kv_valid_len=None) -> jax.Array:
     """Softmax attention, materializing full scores.
 
-    q: (B, Sq, H, D); k/v: (B, Skv, K, D) with H % K == 0 (GQA broadcast).
+    q: (B, Sq, H, D); k: (B, Skv, K, D); v: (B, Skv, K, Dv) with
+    H % K == 0 (GQA broadcast).
     With kv_valid_len: mask positions t >= valid_len (decode against cache);
     query i is aligned so that position of q[i] = valid_len - Sq + i.
     """
@@ -34,7 +35,7 @@ def naive_attention(q, k, v, *, causal: bool = True,
         s = jnp.where(mask[None, None, None], s, -jnp.inf)
     a = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgst,btkd->bskgd", a, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, D).astype(q.dtype)
+    return o.reshape(B, Sq, H, -1).astype(q.dtype)
 
 
 def naive_ssd(x, dt, A, B, C, D) -> jax.Array:

@@ -16,24 +16,47 @@ import jax.numpy as jnp
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                # the router's width
     top_k: int
     d_expert: int                 # per-expert FFN hidden dim
     n_shared: int = 0             # shared (always-on) experts
-    capacity_factor: float = 1.25
-    first_dense: int = 0          # first k layers use a dense FFN instead
-    d_first_dense: int = 0
-    token_chunk: int = 0          # process tokens in chunks of this size
-                                  # (bounds the (T*k, d) dispatch buffers)
+    first_dense: int = 0          # first k layers use the dense FFN (d_ff)
+    n_held: int = 0               # experts 0..n_held-1 held here: this
+                                  # chip's share (0 = all of them)
+    norm_topk_prob: bool = True   # renormalise the top-k weights to sum 1
+    seq_aux: bool = False         # balance loss per sequence, not per batch
+    aux_alpha: float = 0.01       # weight of the balance loss
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
     kv_lora: int = 512            # compressed kv dim (cached at decode)
-    q_lora: int = 1536
+    q_lora: Optional[int] = 1536  # None: q straight from x, no compression
     d_nope: int = 128             # per-head non-rotary q/k dim
     d_rope: int = 64              # shared rotary key dim
     d_v: int = 128
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 applies it:
+    the frequencies, and the softmax scale times ``mscale_all_dim``'s
+    factor squared.  cos and sin keep their unit length: DeepSeek-V2
+    scales them by mscale's factor over mscale_all_dim's, which is 1."""
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+    def __post_init__(self):
+        assert self.mscale == self.mscale_all_dim, \
+            "cos and sin would need YaRN's mscale factor"
 
 
 @dataclass(frozen=True)
@@ -67,6 +90,7 @@ class ModelConfig:
     d_head: int = 0               # defaults to d_model // n_heads
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0    # chatglm-style 2d rope: rotate this fraction
+    rope_scaling: Optional[YaRNConfig] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
@@ -138,7 +162,8 @@ class ModelConfig:
         # attention side
         if self.mla:
             m = self.mla
-            attn = (d * m.q_lora + m.q_lora * self.n_heads * (m.d_nope + m.d_rope)
+            d_q = self.n_heads * (m.d_nope + m.d_rope)
+            attn = ((d * m.q_lora + m.q_lora * d_q if m.q_lora else d * d_q)
                     + d * (m.kv_lora + m.d_rope)
                     + m.kv_lora * self.n_heads * (m.d_nope + m.d_v)
                     + self.n_heads * m.d_v * d)
@@ -147,9 +172,9 @@ class ModelConfig:
                 2 * d * self.n_kv * self.d_head + self.n_heads * self.d_head * d
         if self.moe:
             mo = self.moe
-            n_routed = mo.top_k if active_only else mo.n_experts
+            n_routed = mo.top_k if active_only else mo.held
             ffn = (n_routed + mo.n_shared) * 3 * d * mo.d_expert
-            dense_ff = mo.first_dense * 3 * d * mo.d_first_dense
+            dense_ff = mo.first_dense * 3 * d * self.d_ff
             ffn_total = (L - mo.first_dense) * ffn + dense_ff
         else:
             ffn_total = L * 3 * d * self.d_ff

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 from . import dist
-from .config import ModelConfig
+from .config import ModelConfig, YaRNConfig
 
 Params = Dict[str, jax.Array]
 
@@ -36,13 +36,49 @@ def rmsnorm(p: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 
 
 # ----------------------------------------------------------------------- rope
-def rope_freqs(d_rot: int, theta: float) -> jax.Array:
-    return 1.0 / theta ** (jnp.arange(0, d_rot, 2, dtype=jnp.float32) / d_rot)
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_freqs(d_rot: int, theta: float,
+               scaling: Optional[YaRNConfig] = None) -> jax.Array:
+    """Inverse frequencies of the d_rot / 2 rotated pairs.  With YaRN
+    scaling, the pairs that turn more than ``beta_fast`` times over the
+    original context keep their frequency, those that turn fewer than
+    ``beta_slow`` times are divided by ``factor``, and a linear ramp
+    blends the ones between (DeepSeek-V2's ``yarn_find_correction_range``
+    and ``yarn_linear_ramp_mask``)."""
+    base = theta ** (jnp.arange(0, d_rot, 2, dtype=jnp.float32) / d_rot)
+    if scaling is None:
+        return 1.0 / base
+    s = scaling
+
+    def dim(rotations):
+        return (d_rot * math.log(s.original_max_position
+                                 / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(dim(s.beta_fast)), 0)
+    hi = min(math.ceil(dim(s.beta_slow)), d_rot - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(d_rot // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (1.0 / (s.factor * base)) * ramp + (1.0 / base) * keep
+
+
+def softmax_scale(d_qk: int, scaling: Optional[YaRNConfig]) -> float:
+    """d_qk ** -0.5, times YaRN's mscale_all_dim factor squared."""
+    m = (_yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+         if scaling is not None and scaling.mscale_all_dim else 1.0)
+    return d_qk ** -0.5 * m * m
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               fraction: float = 1.0) -> jax.Array:
-    """Rotary embedding on the first `fraction` of head dims.
+               fraction: float = 1.0,
+               scaling: Optional[YaRNConfig] = None) -> jax.Array:
+    """Rotary embedding on the first `fraction` of head dims, rotating
+    interleaved pairs (0, 1), (2, 3), ...
 
     x: (..., S, H, D); positions: (..., S) broadcastable.
     """
@@ -52,7 +88,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
     if d_rot == 0:
         return x
     xr, xp = x[..., :d_rot], x[..., d_rot:]
-    freqs = rope_freqs(d_rot, theta)                     # (d_rot/2,)
+    freqs = rope_freqs(d_rot, theta, scaling)           # (d_rot/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, d_rot/2)
     ang = ang[..., None, :]                               # head axis
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -154,11 +190,16 @@ def init_mla(key, cfg: ModelConfig) -> Params:
     m, d, H = cfg.mla, cfg.d_model, cfg.n_heads
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 6)
+    d_q = m.d_nope + m.d_rope
+    if m.q_lora:
+        q = {"wq_a": _init(ks[0], (d, m.q_lora), d ** -0.5, dt),
+             "wq_b": _init(ks[1], (m.q_lora, H, d_q), m.q_lora ** -0.5, dt)}
+    else:
+        q = {"wq": _init(ks[0], (d, H, d_q), d ** -0.5, dt)}
     return {
-        "wq_a": _init(ks[0], (d, m.q_lora), d ** -0.5, dt),
-        "wq_b": _init(ks[1], (m.q_lora, H, m.d_nope + m.d_rope),
-                      m.q_lora ** -0.5, dt),
+        **q,
         "wkv_a": _init(ks[2], (d, m.kv_lora), d ** -0.5, dt),
+        "kv_norm": init_rmsnorm(m.kv_lora, dt),
         "wk_rope": _init(ks[3], (d, m.d_rope), d ** -0.5, dt),
         "wkv_b": _init(ks[4], (m.kv_lora, H, m.d_nope + m.d_v),
                        m.kv_lora ** -0.5, dt),
@@ -174,23 +215,30 @@ def mla_fwd(p: Params, x: jax.Array, cfg: ModelConfig, *,
             kv_start: Optional[jax.Array] = None):
     """Multi-head latent attention (DeepSeek-V2).
 
-    Cache stores only (c_kv, k_rope): (B,S,kv_lora) + (B,S,d_rope) — the
-    compressed latents.  Decode uses the *absorbed* formulation (Wkv_b
-    folded into the query/output) so per-step FLOPs scale with kv_lora,
-    not H x (d_nope + d_v).
+    The kv latent is RMS-normalised before its expansion and before it is
+    cached.  Cache stores only (c_kv, k_rope): (B,S,kv_lora) +
+    (B,S,d_rope) — the compressed latents.  Decode uses the *absorbed*
+    formulation (Wkv_b folded into the query/output) so per-step FLOPs
+    scale with kv_lora, not H x (d_nope + d_v).
     """
     m = cfg.mla
     ct = jnp.dtype(cfg.compute_dtype)
-    H = cfg.n_heads
-    q = jnp.einsum("bsd,dq->bsq", x, p["wq_a"].astype(ct))
-    q = dist.constrain_heads(
-        jnp.einsum("bsq,qhk->bshk", q, p["wq_b"].astype(ct)))
+    if m.q_lora:
+        q = jnp.einsum("bsd,dq->bsq", x, p["wq_a"].astype(ct))
+        q = jnp.einsum("bsq,qhk->bshk", q, p["wq_b"].astype(ct))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(ct))
+    q = dist.constrain_heads(q)
     q_nope, q_rope = q[..., :m.d_nope], q[..., m.d_nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    c_kv = jnp.einsum("bsd,dc->bsc", x, p["wkv_a"].astype(ct))
+    yarn = cfg.rope_scaling
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, scaling=yarn)
+    c_kv = rmsnorm(p["kv_norm"],
+                   jnp.einsum("bsd,dc->bsc", x, p["wkv_a"].astype(ct)),
+                   cfg.norm_eps)
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["wk_rope"].astype(ct))
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
-    scale = 1.0 / math.sqrt(m.d_nope + m.d_rope)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        scaling=yarn)[:, :, 0]
+    scale = softmax_scale(m.d_nope + m.d_rope, yarn)
 
     if cache is None:
         kv = dist.constrain_heads(

@@ -63,10 +63,7 @@ def _init_block(key, cfg: ModelConfig, moe_layer: bool) -> Params:
     if moe_layer:
         p["moe"] = moe_mod.init_moe(k2, cfg)
     else:
-        d_ff = cfg.d_ff
-        if cfg.moe and cfg.moe.first_dense:
-            d_ff = cfg.moe.d_first_dense
-        p["ffn"] = init_swiglu(k3, cfg.d_model, d_ff, dt)
+        p["ffn"] = init_swiglu(k3, cfg.d_model, cfg.d_ff, dt)
     return p
 
 
@@ -82,10 +79,10 @@ def _block_fwd(p: Params, x, cfg: ModelConfig, *, positions, cache=None,
     x = x + h
     hn = rmsnorm(p["ln2"], x, cfg.norm_eps)
     if moe_layer:
-        h, aux = moe_mod.moe_fwd(p["moe"], hn, cfg)
+        h, moe = moe_mod.moe_fwd(p["moe"], hn, cfg)
     else:
-        h, aux = swiglu_fwd(p["ffn"], hn, cfg.compute_dtype), 0.0
-    return dist.constrain_batch(x + h), new_cache, aux
+        h, moe = swiglu_fwd(p["ffn"], hn, cfg.compute_dtype), 0.0
+    return dist.constrain_batch(x + h), new_cache, moe
 
 
 # ================================================================== init
@@ -189,10 +186,12 @@ class TrainBatch(NamedTuple):
 
 
 def forward(params: Params, batch: TrainBatch, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """Returns (logits (B,S,V) or (B,S_text,V), aux_loss)."""
+    """Returns (logits (B,S,V) or (B,S_text,V), MoE readings): the MoE
+    layers' (N_STATS,) readings summed over layers, the balance loss
+    first (zeros without MoE layers)."""
     fam = cfg.family
     x = embed(params["embed"], batch.tokens, cfg)
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = jnp.zeros((moe_mod.N_STATS,), jnp.float32)
     if fam == "vlm" and batch.extra is not None:
         ct = jnp.dtype(cfg.compute_dtype)
         patches = jnp.einsum("bpd,de->bpe", batch.extra.astype(ct),
@@ -275,9 +274,12 @@ def forward(params: Params, batch: TrainBatch, cfg: ModelConfig) -> Tuple[jax.Ar
     return logits, aux_total
 
 
-def loss_fn(params: Params, batch: TrainBatch, cfg: ModelConfig,
-            aux_coef: float = 0.01):
-    logits, aux = forward(params, batch, cfg)
+def loss_fn(params: Params, batch: TrainBatch, cfg: ModelConfig):
+    """Mean next-token NLL over the unembed rows, 1e-4 of the mean squared
+    log Z, and the MoE balance loss at ``aux_alpha``.  Metrics of MoE
+    models add the held experts' readings (``moe_rows`` and
+    ``moe_rows_max``)."""
+    logits, moe = forward(params, batch, cfg)
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
     # gold logit via masked reduction: take_along_axis over the
@@ -289,8 +291,16 @@ def loss_fn(params: Params, batch: TrainBatch, cfg: ModelConfig,
                              logits, 0.0), axis=-1)
     nll = (logz - gold).mean()
     zloss = 1e-4 * (logz ** 2).mean()
-    loss = nll + zloss + aux_coef * aux
-    return loss, {"nll": nll, "aux": aux, "zloss": zloss}
+    aux = moe[0]
+    loss = nll + zloss + (cfg.moe.aux_alpha * aux if cfg.moe else 0.0)
+    metrics = {"nll": nll, "aux": aux, "zloss": zloss}
+    if cfg.moe:
+        metrics.update(zip(MOE_METRICS, moe[1:]))
+    return loss, metrics
+
+
+#: loss_fn's MoE readings, summed over layers (and microbatches)
+MOE_METRICS = ("moe_rows", "moe_rows_max")
 
 
 # ======================================================== caches + decode step

@@ -1,44 +1,55 @@
-"""Mixture-of-Experts FFN with expert parallelism.
+"""Dropless mixture-of-experts FFN over the experts held here.
 
-Experts are sharded over the mesh's `model` axis.  Because token
-activations are replicated over `model` between blocks (TP layout), each
-expert shard can gather the tokens routed to *its* experts locally and the
-shard outputs combine with a single psum — the same collective cost as a
-dense TP FFN, with no all-to-all and no dense dispatch einsum (whose
-E x C FLOPs multiplier would swamp the roofline).
+The router keeps its full width and picks every token's top-k experts.
+This device computes the part of the result that its own experts give:
+the experts it holds (the first ``MoEConfig.n_held``, the chip's share
+of an expert-parallel deployment), or their slice over the mesh's
+``model`` axis.  What absent experts would add is left out, and
+the shard outputs over ``model`` combine with one psum: token
+activations are replicated over ``model`` between blocks (TP layout), so
+each shard routes locally and no all-to-all is needed.
 
-Dispatch is capacity-based (GShard-style token dropping) implemented with
-sort-free scatter/gather so dispatch costs O(T k d) moves and ~0 FLOPs.
+Every assignment to a held expert is computed; none is dropped.  The
+held assignments are grouped by expert into one static bound of rows
+(T * min(k, held) plus one tile per expert, since each group starts on a
+tile of ``kernels.ops.gmm_tile`` rows), and one grouped matrix product
+per weight (:func:`kernels.ops.grouped_matmul`) runs the experts' SwiGLU
+over the live tiles only.  The shared experts run once, densely.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops as kops
 from .config import ModelConfig
 from .layers import _init
 
 Params = Dict[str, jax.Array]
 
 # mesh context lives in models.dist; re-exported here for callers
-from .dist import get_mesh, set_mesh  # noqa: E402
+from .dist import get_mesh, set_mesh  # noqa: E402,F401
 from . import dist as _dist           # noqa: E402
+
+#: the MoE readings a layer adds to the model's carry: the balance loss,
+#: then assignments computed by held experts and the largest held
+#: expert's assignments
+N_STATS = 3
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
     m, d = cfg.moe, cfg.d_model
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 5)
+    E = m.held
     p = {
         "router": _init(ks[0], (d, m.n_experts), d ** -0.5, jnp.float32),
-        "w1": _init(ks[1], (m.n_experts, d, m.d_expert), d ** -0.5, dt),
-        "w3": _init(ks[2], (m.n_experts, d, m.d_expert), d ** -0.5, dt),
-        "w2": _init(ks[3], (m.n_experts, m.d_expert, d), m.d_expert ** -0.5, dt),
+        "w1": _init(ks[1], (E, d, m.d_expert), d ** -0.5, dt),
+        "w3": _init(ks[2], (E, d, m.d_expert), d ** -0.5, dt),
+        "w2": _init(ks[3], (E, m.d_expert, d), m.d_expert ** -0.5, dt),
     }
     if m.n_shared:
         f = m.n_shared * m.d_expert
@@ -51,111 +62,179 @@ def init_moe(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def _capacity(n_tokens: int, cfg: ModelConfig) -> int:
+def route(x: jax.Array, router: jax.Array, cfg: ModelConfig):
+    """Softmax router over all ``n_experts`` and greedy top-k.  x:
+    (..., d).  Returns (probs (..., E), weights (..., k), experts (..., k));
+    the weights are renormalised only with ``norm_topk_prob``."""
     m = cfg.moe
-    c = math.ceil(n_tokens * m.top_k / m.n_experts * m.capacity_factor)
-    return max(8, -(-c // 8) * 8)
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ router, axis=-1)
+    top_w, top_e = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return probs, top_w, top_e
 
 
-def _moe_local(x2d, router, w1, w3, w2, cfg: ModelConfig,
-               e_start, n_local: int, capacity: int):
-    """Per-shard MoE: route all local tokens, run the local expert slice.
-
-    x2d: (T, d); w*: (E_loc, ...); e_start: first local expert id.
-    Returns (partial y (T, d), partial aux-loss scalars).
+def balance_loss(probs: jax.Array, top_e: jax.Array,
+                 cfg: ModelConfig) -> jax.Array:
+    """E * sum_e (share of picks of e) x (mean router probability of e),
+    over each sequence (``seq_aux``, DeepSeek-V2) or over the whole batch
+    (Switch), averaged over those groups.  probs (B, S, E), top_e (B, S, k).
     """
     m = cfg.moe
+    E = m.n_experts
+    picks = jax.nn.one_hot(top_e, E, dtype=jnp.float32).sum(-2)  # (B, S, E)
+    axes = (1,) if m.seq_aux else (0, 1)
+    share = jnp.mean(picks, axis=axes) / m.top_k
+    return E * jnp.mean(jnp.sum(share * jnp.mean(probs, axis=axes), -1))
+
+
+def held_experts(x2d, top_w, top_e, w1, w3, w2, e_start, n_held: int):
+    """The held experts' part of the layer's output, dropless.
+
+    x2d (T, d); top_w, top_e (T, k); w* (n_held, ...) for experts
+    ``e_start .. e_start + n_held - 1``.  Returns (y (T, d), per-expert
+    assignments (n_held,)).
+    """
     T, d = x2d.shape
-    ct = x2d.dtype
-    logits = (x2d.astype(jnp.float32) @ router).astype(jnp.float32)  # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, m.top_k)                      # (T, k)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-    # ---- flatten assignments, keep only local experts ----------------------
-    A = T * m.top_k
-    eid = top_e.reshape(A)
-    gate = top_w.reshape(A)
-    tok = jnp.repeat(jnp.arange(T), m.top_k)
-    local = (eid >= e_start) & (eid < e_start + n_local)
-    el = jnp.where(local, eid - e_start, 0)
-    onehot = (el[:, None] == jnp.arange(n_local)[None]) & local[:, None]
-    pos = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1
-    pos = jnp.take_along_axis(pos, el[:, None], axis=1)[:, 0]
-    keep = local & (pos < capacity)
-    slot = jnp.where(keep, pos, capacity)          # overflow -> trash slot
-    # ---- dispatch: (E_loc, C+1, d) buffer ----------------------------------
-    buf = jnp.zeros((n_local, capacity + 1, d), ct)
-    buf = buf.at[el, slot].add(jnp.where(keep[:, None], x2d[tok], 0))
-    buf = buf[:, :capacity]
-    # ---- expert FFN (batched over local experts) ---------------------------
-    g = jnp.einsum("ecd,edf->ecf", buf, w1.astype(ct))
-    u = jnp.einsum("ecd,edf->ecf", buf, w3.astype(ct))
-    h = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, w2.astype(ct))
-    # ---- combine back -------------------------------------------------------
-    hp = jnp.concatenate([h, jnp.zeros((n_local, 1, d), ct)], axis=1)
-    contrib = hp[el, slot] * (gate * keep).astype(ct)[:, None]
-    y = jnp.zeros((T, d), ct).at[tok].add(contrib)
-    # ---- load-balance aux (Switch-style), local partial sums ---------------
-    frac_prob = jnp.mean(probs, axis=0)                    # (E,)
-    assigned = jnp.zeros((m.n_experts,), jnp.float32).at[eid].add(
-        jnp.ones((A,), jnp.float32))
-    return y, frac_prob, assigned, jnp.asarray(T, jnp.float32)
+    k = top_e.shape[1]
+    tm = kops.gmm_tile(T * min(k, n_held), n_held)
+    # a token picks k distinct experts, so at most min(k, held) held
+    # ones, and a group's padding is under a tile: the grouped rows never
+    # pass the bound, and no assignment is dropped
+    rows = -(-(T * min(k, n_held) + n_held * (tm - 1)) // tm) * tm
+    held = (top_e >= e_start) & (top_e < e_start + n_held)
+    el = jnp.where(held, top_e - e_start, n_held).reshape(T * k)
+    onehot = el[:, None] == jnp.arange(n_held)[None]        # (T k, n_held)
+    counts = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+    rank = jnp.take_along_axis(
+        jnp.cumsum(onehot, axis=0, dtype=jnp.int32),
+        jnp.minimum(el, n_held - 1)[:, None], axis=1)[:, 0] - 1
+    tiles = -(-counts // tm)
+    first = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                             jnp.cumsum(tiles)[:-1]]) * tm
+    pos = jnp.where(el < n_held,
+                    first[jnp.minimum(el, n_held - 1)] + rank, rows)
+    # each row's token; group padding and rows past the live tiles name
+    # no token (T, out of bounds) and read zeros
+    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    src = jnp.full((rows,), T, jnp.int32).at[pos].set(tok, mode="drop")
+    pos = pos.reshape(T, k)
+    xs = _dispatch(x2d, src, pos)
+    g = kops.grouped_matmul(xs, w1.astype(x2d.dtype), counts, tm)
+    u = kops.grouped_matmul(xs, w3.astype(x2d.dtype), counts, tm)
+    h = kops.grouped_matmul((jax.nn.silu(g) * u).astype(x2d.dtype),
+                            w2.astype(x2d.dtype), counts, tm)
+    y = _combine(h, top_w.astype(x2d.dtype), src, pos)
+    return y, counts
+
+
+# Dispatch and combine move rows by two maps the forward knows both ways:
+# each row's token (src) and each token's rows (pos, ``rows`` where the
+# pick is not held).  Each transpose is then a gather by the other map,
+# not a scatter-add; rows past the live tiles are never read.
+def _pick(a, idx):
+    return a.at[idx].get(mode="fill", fill_value=0)
+
+
+def _sum_picks(a, pos, w=None):
+    """sum_j (w[:, j] x) a[pos[:, j]], one pick at a time: no (T, k, d)
+    buffer."""
+    out = 0
+    for j in range(pos.shape[1]):
+        rows = _pick(a, pos[:, j])
+        out = out + (rows if w is None else w[:, j, None] * rows)
+    return out
+
+
+@jax.custom_vjp
+def _dispatch(x2d, src, pos):
+    """The rows' tokens: x2d[src], zeros where src is out of bounds."""
+    return _pick(x2d, src)
+
+
+def _dispatch_fwd(x2d, src, pos):
+    return _pick(x2d, src), (src, pos)
+
+
+def _dispatch_bwd(res, dxs):
+    src, pos = res
+    return _sum_picks(dxs, pos), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(h, w, src, pos):
+    """Each token's picks, weighted: sum_j w[:, j] h[pos[:, j]]."""
+    return _sum_picks(h, pos, w)
+
+
+def _combine_fwd(h, w, src, pos):
+    return _combine(h, w, src, pos), (h, w, src, pos)
+
+
+def _combine_bwd(res, dy):
+    h, w, src, pos = res
+    # each row's weight: its pick's (0 on padding and dead rows)
+    w_row = jnp.zeros((h.shape[0],), w.dtype).at[pos.reshape(-1)].set(
+        w.reshape(-1), mode="drop")
+    dh = w_row[:, None] * _pick(dy, src)
+    dw = jnp.stack([jnp.sum(_pick(h, pos[:, j]) * dy, axis=-1)
+                    for j in range(pos.shape[1])], axis=1).astype(w.dtype)
+    return dh, dw, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _stats(counts, model_axis: bool):
+    """(assignments, largest held expert's) as float32."""
+    total, top = jnp.sum(counts), jnp.max(counts)
+    if model_axis:
+        total = jax.lax.psum(total, "model")
+        top = jax.lax.pmax(top, "model")
+    return jnp.stack([total, top]).astype(jnp.float32)
 
 
 def moe_fwd(p: Params, x: jax.Array, cfg: ModelConfig
             ) -> Tuple[jax.Array, jax.Array]:
-    """Routed experts (+optional shared experts).  Returns (y, aux_loss)."""
+    """Routed experts held here (+ shared experts).  Returns (y, readings
+    (N_STATS,)): the balance loss, then the held experts' assignments
+    and the largest held expert's."""
     m = cfg.moe
     B, S, d = x.shape
+    probs, top_w, top_e = route(x, p["router"], cfg)
+    aux = balance_loss(probs, top_e, cfg)
     mesh = _dist.get_mesh()
     if mesh is not None and "model" in mesh.axis_names:
         ep = mesh.shape["model"]
-        n_local = m.n_experts // ep
-        cap = _capacity(B * S // _batch_shards(mesh), cfg)
+        n_local = m.held // ep
+        bspec = P(_flat_batch_spec(), None, None)
 
-        def shard_fn(xs, router, w1, w3, w2):
-            T = xs.shape[0] * xs.shape[1]
-            j = jax.lax.axis_index("model")
-            tc = m.token_chunk
-            if tc and T > tc and T % tc == 0:
-                # chunked dispatch: capacity and the (T*k, d) gather/
-                # scatter buffers scale with the chunk, not the batch
-                cap_c = max(8, -(-cap * tc // T // 8) * 8)
-
-                def chunk_fn(xc):
-                    return _moe_local(xc, router, w1, w3, w2, cfg,
-                                      j * n_local, n_local, cap_c)
-                y, fp, asg, t = jax.lax.map(
-                    chunk_fn, xs.reshape(T // tc, tc, d))
-                y = y.reshape(T, d)
-                fp = jnp.mean(fp, axis=0)
-                asg = jnp.sum(asg, axis=0)
-                t = jnp.sum(t)
-            else:
-                y, fp, asg, t = _moe_local(xs.reshape(T, d), router, w1, w3,
-                                           w2, cfg, j * n_local, n_local, cap)
+        def shard_fn(xs, tw, te, w1, w3, w2):
+            b, s = xs.shape[:2]
+            e0 = jax.lax.axis_index("model") * n_local
+            y, counts = held_experts(
+                xs.reshape(b * s, d), tw.reshape(b * s, -1),
+                te.reshape(b * s, -1), w1, w3, w2, e0, n_local)
+            counts = jax.lax.psum(counts, _dist.batch_axes())
             y = jax.lax.psum(y, "model")
-            ba = _dist.batch_axes()
-            fp = jax.lax.pmean(fp, ba)
-            asg = jax.lax.psum(asg, ba + ("model",))
-            t = jax.lax.psum(t, ba + ("model",))
-            return y.reshape(xs.shape), fp, asg, t
+            return y.reshape(xs.shape), _stats(counts, True)
 
-        y, fp, asg, t = jax.shard_map(
+        y, stats = jax.shard_map(
             shard_fn, mesh=mesh,
-            in_specs=(P(_flat_batch_spec(), None, None),
-                      P(None, None), P("model", None, None),
+            in_specs=(bspec, bspec, bspec, P("model", None, None),
                       P("model", None, None), P("model", None, None)),
-            out_specs=(P(_flat_batch_spec(), None, None), P(None), P(None), P()),
+            out_specs=(bspec, P()),
             check_vma=False,
-        )(x, p["router"], p["w1"], p["w3"], p["w2"])
+        )(x, top_w, top_e, p["w1"], p["w3"], p["w2"])
     else:
-        cap = _capacity(B * S, cfg)
-        y, fp, asg, t = _moe_local(x.reshape(B * S, d), p["router"], p["w1"],
-                                   p["w3"], p["w2"], cfg, 0, m.n_experts, cap)
+        y, counts = held_experts(
+            x.reshape(B * S, d), top_w.reshape(B * S, -1),
+            top_e.reshape(B * S, -1), p["w1"], p["w3"], p["w2"], 0, m.held)
         y = y.reshape(B, S, d)
-    frac_tokens = asg / jnp.maximum(t * m.top_k, 1.0)
-    aux = m.n_experts * jnp.sum(fp * frac_tokens)
+        stats = _stats(counts, False)
     if m.n_shared:
         sh = p["shared"]
         ct = x.dtype
@@ -163,16 +242,9 @@ def moe_fwd(p: Params, x: jax.Array, cfg: ModelConfig
         u = jnp.einsum("bsd,df->bsf", x, sh["w_up"].astype(ct))
         y = y + jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u,
                            sh["w_down"].astype(ct))
-    return y, aux
+    return y, jnp.concatenate([aux[None], stats])
 
 
 def _flat_batch_spec():
     ba = _dist.batch_axes()
     return ba if len(ba) > 1 else ba[0]
-
-
-def _batch_shards(mesh) -> int:
-    n = 1
-    for a in _dist.batch_axes():
-        n *= mesh.shape[a]
-    return n

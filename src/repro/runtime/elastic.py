@@ -20,7 +20,9 @@ the paper's "negligible" malleable resize assumption.
 
 Each operation records its spans (``repro.telemetry``), keyed by the job's
 id: ``train.step`` (``train.batch``, ``train.place``, ``train.dispatch``,
-``train.sync``), ``elastic.preempt`` (the checkpoint's ``ckpt.*`` spans,
+``train.sync``; an MoE model's ``moe.rows`` and ``moe.rows_max`` after
+it, each a counter and a row over the step),
+``elastic.preempt`` (the checkpoint's ``ckpt.*`` spans,
 ``elastic.free``), ``elastic.resume`` (``elastic.jit``, ``ckpt.load``,
 ``ckpt.place``) and ``elastic.resize``.
 """
@@ -36,6 +38,7 @@ from jax.sharding import Mesh
 from repro import telemetry
 from repro.models import init_params, set_mesh
 from repro.models.config import ModelConfig
+from repro.models.model import MOE_METRICS
 from repro.sharding import batch_axes, batch_sharding, tree_shardings
 from repro.training import (AdamW, checkpoint, make_train_state,
                             make_train_step, synthetic_batch)
@@ -139,6 +142,13 @@ class ElasticJob:
             with telemetry.span("train.sync"):
                 metrics = {k: float(v) for k, v in metrics.items()}
         telemetry.count("train.tokens", tokens)
+        for k in MOE_METRICS:
+            if k in metrics:
+                # a row over the step too, so a window's share is readable
+                name = k.replace("_", ".", 1)
+                telemetry.count(name, metrics[k])
+                telemetry.record(name, sp.t0, sp.t1, key=self.jid,
+                                 n=metrics[k])
         self.step_idx += 1
         self.monitor.observe(sp.t1 - sp.t0)
         if self.ckpt_dir and self.step_idx % self.ckpt_every == 0:

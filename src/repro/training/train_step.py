@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import TrainBatch, loss_fn
+from repro.models.model import MOE_METRICS
 from repro.models.config import ModelConfig
 from .optimizer import AdamW, AdamWState
 
@@ -89,7 +90,9 @@ def make_train_step(cfg: ModelConfig, opt: AdamW, *,
                 acc_fn, (jnp.zeros(()), zeros), mb)
             loss = loss / microbatches
             grads = jax.tree.map(lambda g: g / microbatches, grads)
-            metrics = jax.tree.map(lambda m: m[-1], metrics)
+            # the last microbatch's metrics; the MoE counts of all of them
+            metrics = {k: v.sum(0) if k in MOE_METRICS else v[-1]
+                       for k, v in metrics.items()}
 
         ef = state.ef
         if compress_grads:

@@ -3,10 +3,11 @@
 The TPU compiler refuses what interpret mode accepts (block shapes off the
 tiling, too much VMEM, a kernel XLA cannot partition), so the kernels of
 the main path are compiled here at real widths: the Pallas attention
-kernels at internvl2-1b widths (14 query / 2 KV heads, d_head 64) and the
-vmapped scheduler decision kernels at the lane width of a Theta-scale
-sweep, in both replay dtypes.  Nothing runs: a pass says the chip's
-compiler accepts the program, not that it is correct or fast.
+kernels at internvl2-1b widths (14 query / 2 KV heads, d_head 64) and at
+deepseek-v2-lite's (16 heads, q/k 192, v 128), its grouped expert
+products, and the vmapped scheduler decision kernels at the lane width of
+a Theta-scale sweep, in both replay dtypes.  Nothing runs: a pass says
+the chip's compiler accepts the program, not that it is correct or fast.
 
 The topology is described inside a fixture, never at import, so every
 test worker collects the same tests and only the worker that runs this
@@ -65,6 +66,47 @@ def test_flash_attention_compiles(one_chip, B, S):
             flash_attention(q, k, v, s).astype(jnp.float32)),
         argnums=(0, 1, 2)))
     grad.lower(q, kv, kv, start).compile()
+
+
+def test_flash_attention_mla_compiles(one_chip):
+    """deepseek-v2-lite's training shape: a microbatch of two 8,192-token
+    sequences, 16 heads, q/k 192 and v 128."""
+    B, S, H, D, Dv = 2, 8192, 16, 192, 128
+    q, v = _sds(one_chip, (B, S, H, D)), _sds(one_chip, (B, S, H, Dv))
+    start = _sds(one_chip, (B,), jnp.int32)
+    fwd = jax.jit(lambda q, k, v, s: flash_attention(q, k, v, s))
+    assert "tpu_custom_call" in fwd.lower(q, q, v, start).compile().as_text()
+    grad = jax.jit(jax.grad(
+        lambda q, k, v, s: jnp.sum(
+            flash_attention(q, k, v, s).astype(jnp.float32)),
+        argnums=(0, 1, 2)))
+    grad.lower(q, q, v, start).compile()
+
+
+@pytest.mark.parametrize("T,k,E", [(16384, 6, 8), (8, 6, 64)])
+def test_moe_grouped_products_compile(one_chip, T, k, E):
+    """The expert layer's grouped products at deepseek-v2-lite's widths
+    over its static bound of rows (T x min(top-k, held), one tile per
+    held expert), for a training microbatch on 8 held experts (256-row
+    tiles) and a decode step of 8 tokens on all 64 (16-row tiles): x W1,
+    h W2, the transposed products of the backward and the weights'
+    gradient."""
+    from repro.kernels import moe_gmm, ops
+    d, F = 2048, 1408
+    tm = ops.gmm_tile(T * min(k, E), E)
+    M = -(-(T * min(k, E) + E * (tm - 1)) // tm) * tm
+    counts = _sds(one_chip, (E,), jnp.int32)
+    for K, N in ((d, F), (F, d)):
+        lhs, rhs = _sds(one_chip, (M, K)), _sds(one_chip, (E, K, N))
+        dout = _sds(one_chip, (M, N))
+        c = jax.jit(lambda x, w, n: moe_gmm.gmm(x, w, n, tm=tm)).lower(
+            lhs, rhs, counts).compile()
+        assert "moe_gmm" in c.as_text()
+        jax.jit(lambda g, w, n: moe_gmm.gmm(g, w, n, tm=tm, transpose=True)
+                ).lower(dout, rhs, counts).compile()
+        c = jax.jit(lambda x, g, n: moe_gmm.tgmm(x, g, n, tm=tm, n_groups=E)
+                    ).lower(lhs, dout, counts).compile()
+        assert "moe_tgmm" in c.as_text()
 
 
 def test_flash_decode_compiles(one_chip):

@@ -249,6 +249,69 @@ def test_flash_attention_long_padded():
         assert float(jnp.abs(a[0, start:] - b[0]).max()) < 1e-4
 
 
+@pytest.mark.parametrize("start", [0, 150])
+def test_flash_attention_mla_widths(start):
+    """Latent attention's widths, q/k 192 and v 128 (as many kv heads as
+    q heads): forward and gradient equal attention over the unpadded
+    sequence, with kv_start zero and inside the second chunk of 96."""
+    S, H, D, Dv = 288, 4, 192, 128
+    q, k, v = rnd(2, S, H, D), rnd(2, S, H, D), rnd(2, S, H, Dv)
+    ct = rnd(2, S, H, Dv)
+    starts = jnp.asarray([0, start], jnp.int32)
+    scale = D ** -0.5 * 1.3     # not a power of two, as MLA's is not
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, starts, scale=scale, block_q=96,
+                               block_kv=96, interpret=True)
+
+    def naive(q, k, v):
+        return ref.naive_attention(q, k, v, scale=scale)
+    o = attend(q, k, v)
+    assert o.shape == (2, S, H, Dv)
+    for b in (0, 1):
+        s0 = int(starts[b])
+        o_ref = naive(q[b:b + 1, s0:], k[b:b + 1, s0:], v[b:b + 1, s0:])
+        assert float(jnp.abs(o[b, s0:] - o_ref[0]).max()) < 1e-5
+    g = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v)[1, start:]
+                                         * ct[1, start:]),
+                 argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda q, k, v: jnp.sum(naive(q, k, v)[0]
+                                             * ct[1, start:]),
+                     argnums=(0, 1, 2))(q[1:, start:], k[1:, start:],
+                                        v[1:, start:])
+    for a, b in zip(g, g_ref):
+        assert float(jnp.abs(a[1, start:] - b[0]).max()) < 1e-4
+        assert float(jnp.sum(jnp.abs(a[1, :start]))) == 0.0
+        assert float(jnp.abs(a[0]).max()) == 0.0
+
+
+def test_flash_attention_mla_dead_first_chunk():
+    """q/k 192, v 128 over four q blocks of 256 and two chunks of 512,
+    with kv_start past the first chunk: that chunk is dead and skipped;
+    the real rows' outputs and gradients equal attention over the
+    unpadded sequence."""
+    S, D, Dv, start = 1024, 192, 128, 600
+    q, k, v = rnd(1, S, 2, D), rnd(1, S, 2, D), rnd(1, S, 2, Dv)
+    starts = jnp.asarray([start], jnp.int32)
+    ct = rnd(1, S, 2, Dv)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, starts, block_q=256, block_kv=512,
+                               interpret=True)
+    o = flash(q, k, v)
+    o_ref = ref.naive_attention(q[:, start:], k[:, start:], v[:, start:])
+    assert float(jnp.abs(o[0, start:] - o_ref[0]).max()) < 1e-4
+    g = jax.grad(lambda q, k, v: jnp.sum(flash(q, k, v)[0, start:]
+                                         * ct[0, start:]),
+                 argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda q, k, v: jnp.sum(
+        ref.naive_attention(q, k, v)[0] * ct[0, start:]),
+        argnums=(0, 1, 2))(q[:, start:], k[:, start:], v[:, start:])
+    for a, b in zip(g, g_ref):
+        assert a.shape[-1] == b.shape[-1]
+        assert float(jnp.abs(a[0, start:] - b[0]).max()) < 1e-4
+
+
 @pytest.mark.parametrize("B,S,block_q,block_kv,live,masked", [
     (1, 2304, 512, 1024, 12, 6),     # train_steady: 6 q blocks, 3 chunks
     (2, 288, 96, 96, 6, 3),          # 3 q blocks, 3 chunks of the same size
