@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import contextlib
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
@@ -89,10 +90,17 @@ class ServeEngine:
         self._n_batches = 0
         self._prefill = jax.jit(
             lambda p, t, st: prefill(p, t, cfg, kv_start=st))
+        donate = (1,) if donate_cache else ()
         self._decode = jax.jit(
             lambda p, c, t, pos, st: decode_step(p, c, t, pos, cfg,
                                                  kv_start=st),
-            donate_argnums=(1,) if donate_cache else ())
+            donate_argnums=donate)
+
+        def greedy_step(p, c, tok, pos, st):
+            logits, c = decode_step(p, c, tok[:, None], pos, cfg,
+                                    kv_start=st)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), pos + 1, c
+        self._step = jax.jit(greedy_step, donate_argnums=donate)
 
     @contextlib.contextmanager
     def _placed(self):
@@ -109,7 +117,7 @@ class ServeEngine:
         if self.mesh is None:
             return jnp.asarray(x)
         n = self.mesh.shape["data"]
-        spec = P("data") if x.shape[0] % n == 0 else P()
+        spec = P("data") if x.ndim and x.shape[0] % n == 0 else P()
         return jax.device_put(x, NamedSharding(self.mesh, spec))
 
     def _start(self, prompts):
@@ -127,59 +135,86 @@ class ServeEngine:
         return next_tok, cache, kv_start, S, logits
 
     def serve_batch(self, requests: List[Request]) -> List[Request]:
-        """Run a padded batch of requests to completion.  Records the
-        spans ``serve.batch``, ``serve.prefill`` and one
-        ``serve.decode_step`` per step, keyed by the batch's number."""
+        """Run a padded batch of requests to completion: each gets greedy
+        tokens until it holds ``max_new_tokens``, emits ``eos_id`` or the
+        cache is full.
+
+        Decode steps are pipelined one deep: one jitted program a step
+        takes the previous step's tokens and position on the device, and
+        step k+1 is dispatched before step k's tokens are read back (their
+        copy to the host starts at dispatch).  The host learns a row hit
+        ``eos_id`` one step late, so at most one step a batch runs past
+        the last live row; its tokens are dropped.  Records the spans
+        ``serve.batch``, ``serve.prefill`` and one ``serve.decode_step``
+        per step handed out, keyed by the batch's number, and the counters
+        ``serve.decode_ahead`` and ``serve.decode_discarded``."""
         self._n_batches += 1
         B = len(requests)
         with self._placed(), telemetry.span(
                 "serve.batch", key=self._n_batches, n=B):
             n_prompt = sum(len(r.prompt) for r in requests)
             with telemetry.span("serve.prefill", n=n_prompt) as pre:
-                next_tok, cache, kv_start, S, _ = self._start(
+                tok, cache, kv_start, S, _ = self._start(
                     [r.prompt for r in requests])
                 with telemetry.span("serve.token_sync", n=B):
-                    toks_np = np.asarray(next_tok)  # waits for the device
-            for i, r in enumerate(requests):
+                    toks_np = np.asarray(tok)  # waits for the device
+            for r in requests:
                 r.first_token_at = pre.t1
-                r.tokens_out.append(int(toks_np[i]))
+            live = np.ones((B,), bool)
+            self._hand_out(requests, live, toks_np)
             telemetry.count("serve.prompt_tokens", n_prompt)
             telemetry.count("serve.padded_tokens", B * S)
-            live = np.ones((B,), bool)
-            n_live, n_out, n_steps = B, B, 0
-            for step in range(1, max(r.max_new_tokens for r in requests)):
-                pos = S + step - 1
-                if pos >= self.max_seq:
-                    break
-                n_steps += 1
-                with telemetry.span("serve.decode_step", n=n_live):
-                    with telemetry.span("serve.dispatch"):
-                        logits, cache = self._decode(
-                            self.params, cache, next_tok[:, None], pos,
-                            kv_start)
-                        next_tok = jnp.argmax(logits, axis=-1).astype(
-                            jnp.int32)
+            # row i's last decode step brings its max_new_tokens-th token
+            # (prefill brought the first); none runs past the cache's end
+            last = np.asarray([r.max_new_tokens - 1 for r in requests])
+            n_cap = self.max_seq - S
+
+            def needed(k):
+                return k <= n_cap and bool((live & (last >= k)).any())
+
+            pos = self._put(np.full((), S, np.int32))
+            unread: deque = deque()     # tokens of dispatched steps
+            n_out, n_sent, n_ahead, k = B, 0, 0, 0
+            while live.any() and (unread or needed(k + 1)):
+                k += 1
+                with telemetry.span("serve.decode_step",
+                                    n=int(live.sum())):
+                    # enqueue up to step k+1, so the device runs it while
+                    # the host reads step k's tokens
+                    send = [j for j in range(n_sent + 1, k + 2)
+                            if needed(j)]
+                    if send:
+                        with telemetry.span("serve.dispatch", n=len(send)):
+                            for j in send:
+                                tok, pos, cache = self._step(
+                                    self.params, cache, tok, pos, kv_start)
+                                tok.copy_to_host_async()
+                                unread.append(tok)
+                                n_ahead += j > k
+                        n_sent = send[-1]
                     with telemetry.span("serve.token_sync", n=B):
-                        toks_np = np.asarray(next_tok)
-                    for i, r in enumerate(requests):
-                        if not live[i]:
-                            continue
-                        r.tokens_out.append(int(toks_np[i]))
-                        n_out += 1
-                        if len(r.tokens_out) >= r.max_new_tokens or \
-                                (self.eos_id is not None
-                                 and toks_np[i] == self.eos_id):
-                            live[i] = False
-                            n_live -= 1
-                            r.done_at = time.monotonic()
-                if not n_live:
-                    break
+                        toks_np = np.asarray(unread.popleft())
+                    n_out += int(live.sum())
+                    self._hand_out(requests, live, toks_np)
             telemetry.count("serve.tokens_out", n_out)
-            telemetry.count("serve.decode_slots", B * n_steps)
+            telemetry.count("serve.decode_slots", B * k)
+            telemetry.count("serve.decode_ahead", n_ahead)
+            telemetry.count("serve.decode_discarded", len(unread))
         now = time.monotonic()
         for r in requests:
             r.done_at = r.done_at or now
         return requests
+
+    def _hand_out(self, requests, live, toks_np):
+        """Append a step's tokens to the live rows, and clear ``live`` for
+        the rows they finish."""
+        for i in np.flatnonzero(live):
+            r = requests[i]
+            r.tokens_out.append(int(toks_np[i]))
+            if len(r.tokens_out) >= r.max_new_tokens or \
+                    (self.eos_id is not None and toks_np[i] == self.eos_id):
+                live[i] = False
+                r.done_at = time.monotonic()
 
     def step_logits(self, prompt: np.ndarray,
                     continuation: Sequence[int]) -> np.ndarray:

@@ -186,8 +186,9 @@ def test_serving_engine_batches_and_latency():
 
 
 def test_serve_engine_stays_on_its_devices():
-    """An engine handed devices 2-3 keeps its params and cache there, and
-    its left-padded batch answers like each request alone."""
+    """An engine handed devices 2-3 keeps its params and cache there, its
+    left-padded batch answers like each request alone, and its decode
+    loop gives the tokens a one-device engine gives."""
     out = run_py(SMALL_CFG + """
 import jax, numpy as np
 from repro.models import init_params
@@ -205,9 +206,20 @@ alone = [eng.step_logits(p, [])[0] for p in prompts]
 err = max(np.abs(np.asarray(logits[i], np.float32) - alone[i]).max()
           for i in range(2))
 print("pad_err", err)
+from repro.serving import Request
+solo = ServeEngine(CFG, init_params(jax.random.PRNGKey(0), CFG), max_seq=64)
+prompts.append(rng.integers(0, 256, 9, dtype=np.int32))
+def serve(e, n):
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=6)
+            for i, p in enumerate(prompts[:n])]
+    e.serve_batch(reqs)
+    return [r.tokens_out for r in reqs]
+# two rows split over the two devices, three replicated on both
+print("served_alike", all(serve(eng, n) == serve(solo, n) for n in (2, 3)))
 """, devices=4)
     assert out.split("where")[1].split("\n")[0].strip() == "[2, 3]"
     assert float(out.split("pad_err")[1].split()[0]) < 1e-4
+    assert "served_alike True" in out
 
 
 def test_preempt_frees_device_state_and_resumes_bit_identically():

@@ -208,10 +208,17 @@ def test_serve_batch_records_prefill_and_each_decode_step():
     assert len(steps) == 5 - 1
     assert pre.n == 9 + 16 + 30 and pre.key == batch[0].key
     assert [r.name for r in _children(rows, pre)] == ["serve.token_sync"]
+    sent = []
     for s in steps:
-        assert sorted(r.name for r in _children(rows, s)) == \
-            ["serve.dispatch", "serve.token_sync"]
+        parts = _by_name(_children(rows, s))
+        assert len(parts["serve.token_sync"]) == 1
+        assert len(parts.get("serve.dispatch", [])) <= 1
+        assert set(parts) <= {"serve.dispatch", "serve.token_sync"}
+        sent += [d.n for d in parts.get("serve.dispatch", [])]
         assert s.key == batch[0].key and s.n == 3
+    # the first step enqueues steps 1 and 2, each later one the step
+    # after it, and the last step nothing: no step runs past the batch
+    assert sent == [2, 1, 1]
     after = telemetry.counters()
     got = sum(len(r.tokens_out) for r in reqs)
     assert after["serve.tokens_out"] - before.get("serve.tokens_out", 0) \
